@@ -57,7 +57,8 @@ def aggregate_deltas(params, deltas, coeffs):
         return (p.astype(jnp.float32)
                 + jnp.sum(c * d.astype(jnp.float32), axis=0)).astype(p.dtype)
 
-    return jax.tree.map(upd, params, deltas)
+    with jax.named_scope("fed.aggregate"):
+        return jax.tree.map(upd, params, deltas)
 
 
 def _flatten_leaf(leaf):
@@ -76,8 +77,21 @@ def _flatten_leaf(leaf):
 def flatten_client_deltas(deltas):
     """Stacked-client pytree (leaves (C, ...)) -> one (C, D_total) f32
     buffer, leaves concatenated in jax.tree.leaves order."""
-    return jnp.concatenate(
-        [_flatten_leaf(l) for l in jax.tree.leaves(deltas)], axis=1)
+    with jax.named_scope("fed.flatten"):
+        return jnp.concatenate(
+            [_flatten_leaf(l) for l in jax.tree.leaves(deltas)], axis=1)
+
+
+def _apply_flat(params, agg):
+    """w + agg over the (D_total,) aggregate, unflattened leaf by leaf in
+    jax.tree.leaves order."""
+    p_leaves, treedef = jax.tree.flatten(params)
+    outs, off = [], 0
+    for p in p_leaves:
+        seg = agg[off:off + p.size].reshape(p.shape)
+        outs.append((p.astype(jnp.float32) + seg).astype(p.dtype))
+        off += p.size
+    return jax.tree.unflatten(treedef, outs)
 
 
 def aggregate_deltas_flat(params, deltas, coeffs, *, block: int = 2048,
@@ -106,36 +120,36 @@ def aggregate_deltas_flat(params, deltas, coeffs, *, block: int = 2048,
     D = flat.shape[1]
     block = min(block, max(128, -(-D // 128) * 128))
     if spec.quantized:
-        payload, scales = compress_flat(flat, spec)
-        if sharding is not None:
-            payload, scales = sharding.constrain_compressed(payload, scales)
-            agg = ops.weighted_agg_quant_sharded(
-                coeffs.astype(jnp.float32), payload, scales,
-                chunk=spec.chunk, mesh=sharding.mesh, axis=sharding.axis,
-                block=block, interpret=interpret)
-        else:
-            agg = ops.weighted_agg_quant(
-                coeffs.astype(jnp.float32), payload, scales,
-                chunk=spec.chunk, block=block, interpret=interpret)
-        agg = agg[:D]
+        with jax.named_scope("fed.flatten"):
+            payload, scales = compress_flat(flat, spec)
+        with jax.named_scope("fed.aggregate"):
+            if sharding is not None:
+                payload, scales = sharding.constrain_compressed(payload,
+                                                                scales)
+                agg = ops.weighted_agg_quant_sharded(
+                    coeffs.astype(jnp.float32), payload, scales,
+                    chunk=spec.chunk, mesh=sharding.mesh,
+                    axis=sharding.axis, block=block, interpret=interpret)
+            else:
+                agg = ops.weighted_agg_quant(
+                    coeffs.astype(jnp.float32), payload, scales,
+                    chunk=spec.chunk, block=block, interpret=interpret)
+            agg = agg[:D]
     else:
         if spec.kind == "bf16":
-            flat = flat.astype(jnp.bfloat16)
-        if sharding is not None:
-            flat = sharding.constrain_client(flat)
-            agg = ops.weighted_agg_sharded(
-                coeffs.astype(jnp.float32), flat, mesh=sharding.mesh,
-                axis=sharding.axis, block=block, interpret=interpret)
-        else:
-            agg = ops.weighted_agg(coeffs.astype(jnp.float32), flat,
-                                   block=block, interpret=interpret)
-    p_leaves, treedef = jax.tree.flatten(params)
-    outs, off = [], 0
-    for p in p_leaves:
-        seg = agg[off:off + p.size].reshape(p.shape)
-        outs.append((p.astype(jnp.float32) + seg).astype(p.dtype))
-        off += p.size
-    return jax.tree.unflatten(treedef, outs)
+            with jax.named_scope("fed.flatten"):
+                flat = flat.astype(jnp.bfloat16)
+        with jax.named_scope("fed.aggregate"):
+            if sharding is not None:
+                flat = sharding.constrain_client(flat)
+                agg = ops.weighted_agg_sharded(
+                    coeffs.astype(jnp.float32), flat, mesh=sharding.mesh,
+                    axis=sharding.axis, block=block, interpret=interpret)
+            else:
+                agg = ops.weighted_agg(coeffs.astype(jnp.float32), flat,
+                                       block=block, interpret=interpret)
+    with jax.named_scope("fed.aggregate"):
+        return _apply_flat(params, agg)
 
 
 def aggregate_deltas_compressed_ref(params, deltas, coeffs, compression):
@@ -147,28 +161,28 @@ def aggregate_deltas_compressed_ref(params, deltas, coeffs, compression):
     from repro.core.compression import resolve_compression, round_trip
 
     spec = resolve_compression(compression)
-    flat = round_trip(flatten_client_deltas(deltas), spec)
-    agg = jnp.einsum("k,kd->d", coeffs.astype(jnp.float32), flat)
-    p_leaves, treedef = jax.tree.flatten(params)
-    outs, off = [], 0
-    for p in p_leaves:
-        seg = agg[off:off + p.size].reshape(p.shape)
-        outs.append((p.astype(jnp.float32) + seg).astype(p.dtype))
-        off += p.size
-    return jax.tree.unflatten(treedef, outs)
+    flat = flatten_client_deltas(deltas)
+    with jax.named_scope("fed.flatten"):
+        flat = round_trip(flat, spec)
+    with jax.named_scope("fed.aggregate"):
+        agg = jnp.einsum("k,kd->d", coeffs.astype(jnp.float32), flat)
+        return _apply_flat(params, agg)
 
 
 def accumulate_delta(acc, delta, coeff):
     """Streaming form for the client-sequential mode: acc += c * delta.
     coeff may be a plain python float or a jax scalar."""
-    c = jnp.asarray(coeff, jnp.float32)
-    return jax.tree.map(
-        lambda a, d: a + c * d.astype(jnp.float32), acc, delta)
+    with jax.named_scope("fed.aggregate"):
+        c = jnp.asarray(coeff, jnp.float32)
+        return jax.tree.map(
+            lambda a, d: a + c * d.astype(jnp.float32), acc, delta)
 
 
 def apply_accumulator(params, acc):
-    return jax.tree.map(
-        lambda p, a: (p.astype(jnp.float32) + a).astype(p.dtype), params, acc)
+    with jax.named_scope("fed.aggregate"):
+        return jax.tree.map(
+            lambda p, a: (p.astype(jnp.float32) + a).astype(p.dtype),
+            params, acc)
 
 
 def expected_coeff_stats(scheme: str, p: np.ndarray, trace_samples,

@@ -44,10 +44,11 @@ def local_sgd(loss_fn: Callable, params, client_batches, alpha_e, eta):
             w, g)
         return w, None
 
-    w_end, _ = jax.lax.scan(step, params, (client_batches, alpha_e))
-    return jax.tree.map(
-        lambda e, s: e.astype(jnp.float32) - s.astype(jnp.float32),
-        w_end, params)
+    with jax.named_scope("fed.local_sgd"):
+        w_end, _ = jax.lax.scan(step, params, (client_batches, alpha_e))
+        return jax.tree.map(
+            lambda e, s: e.astype(jnp.float32) - s.astype(jnp.float32),
+            w_end, params)
 
 
 def _constrain_client_deltas(sharding, deltas, param_specs):
@@ -197,15 +198,19 @@ def fed_round_sequential(loss_fn, params, batches, alpha, coeffs, eta, *,
             b_c = _constrain_batch(sharding, b_c, axis_dim=1)
         delta = local_sgd(loss_fn, params, b_c, a_c, eta)
         if spec.active:
-            delta = round_trip_tree(delta, spec)
+            with jax.named_scope("fed.flatten"):
+                delta = round_trip_tree(delta, spec)
         acc = con_acc(accumulate_delta(acc, delta, c_c))
         if with_metrics:
             dn2 = dn2 + sum(jnp.sum(jnp.square(x))
                             for x in jax.tree.leaves(delta))
         return (acc, dn2), None
 
-    (acc, dn2), _ = jax.lax.scan(one_client, (acc0, jnp.float32(0)),
-                                 (batches, alpha, coeffs))
+    # the client loop is local SGD; the accumulate inside it names itself
+    # fed.aggregate, which a profile reads as the innermost scope
+    with jax.named_scope("fed.local_sgd"):
+        (acc, dn2), _ = jax.lax.scan(one_client, (acc0, jnp.float32(0)),
+                                     (batches, alpha, coeffs))
     new_params = apply_accumulator(params, acc)
     if sharding is not None:
         new_params = sharding.constrain_params(new_params, param_specs)

@@ -48,7 +48,6 @@ Usage::
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 from typing import Optional
 
@@ -139,17 +138,18 @@ def device_sample_round(key, active, n, s_cdf, E: int, B: int):
     spans or chunks.  That invariance is what makes mid-stream
     checkpoint/resume bit-exact in device mode (fed/state.py).
     """
-    ks, kb = jax.random.split(key)
-    # inverse-CDF draw of s: s = #{k : cdf[k] < u}
-    u = jax.random.uniform(ks, (n.shape[0],))
-    s = jnp.sum(u[:, None] > s_cdf, axis=-1)
-    s = s.astype(jnp.float32) * active
-    alpha = (jnp.arange(E, dtype=jnp.float32)[None, :]
-             < s[:, None]).astype(jnp.float32)
-    ub = jax.random.uniform(kb, (n.shape[0], E, B))
-    nf = n.astype(jnp.float32)[:, None, None]
-    idx = jnp.minimum((ub * nf).astype(jnp.int32),
-                      n[:, None, None] - 1)
+    with jax.named_scope("fed.sample"):
+        ks, kb = jax.random.split(key)
+        # inverse-CDF draw of s: s = #{k : cdf[k] < u}
+        u = jax.random.uniform(ks, (n.shape[0],))
+        s = jnp.sum(u[:, None] > s_cdf, axis=-1)
+        s = s.astype(jnp.float32) * active
+        alpha = (jnp.arange(E, dtype=jnp.float32)[None, :]
+                 < s[:, None]).astype(jnp.float32)
+        ub = jax.random.uniform(kb, (n.shape[0], E, B))
+        nf = n.astype(jnp.float32)[:, None, None]
+        idx = jnp.minimum((ub * nf).astype(jnp.int32),
+                          n[:, None, None] - 1)
     return alpha, idx
 
 
@@ -401,10 +401,6 @@ class RoundEngine:
         self._m_traces = tel.counter(
             "engine_traces_total",
             "jitted chunk (re)traces — actual scan compiles")
-        self._m_spans = tel.counter(
-            "engine_spans_total", "run_span dispatches")
-        self._m_rounds = tel.counter(
-            "engine_rounds_total", "rounds executed by run_span")
         # analytic client->server traffic (core/compression.wire_bytes),
         # labeled by wire format — incremented per span from the realized
         # participation counts
@@ -620,15 +616,23 @@ class RoundEngine:
     # -- jitted chunk builders ------------------------------------------------
     def _round_core(self, params, data, alpha, idx, tau, p,
                     rb_tau0, rb_boost, lr_shift):
-        gather = jax.vmap(lambda d, i: jnp.take(d, i, axis=0))
-        batches = self.task.make_batch(
-            {name: gather(buf, idx) for name, buf in data.items()})
-        s = jnp.sum(alpha, axis=-1)
-        coeffs = scheme_coefficients(self.scheme, p, s, self.E)
-        # fast-reboot boost, exact O((tau-tau0)^-2) decay at every in-chunk
-        # tau; rb_boost == 1 for never-rebooted clients => multiplier 1
-        dt = jnp.maximum(tau - rb_tau0, 0).astype(jnp.float32)
-        coeffs = coeffs * (1.0 + (rb_boost - 1.0) / jnp.square(1.0 + dt))
+        # the round's phases carry jax.named_scope names (fed.sample,
+        # fed.gather, fed.local_sgd, fed.flatten, fed.aggregate) in the
+        # HLO's op_name metadata, so a device profile attributes its ops
+        # to them; the compiled program is otherwise unchanged
+        with jax.named_scope("fed.gather"):
+            gather = jax.vmap(lambda d, i: jnp.take(d, i, axis=0))
+            batches = self.task.make_batch(
+                {name: gather(buf, idx) for name, buf in data.items()})
+        with jax.named_scope("fed.aggregate"):
+            s = jnp.sum(alpha, axis=-1)
+            coeffs = scheme_coefficients(self.scheme, p, s, self.E)
+            # fast-reboot boost, exact O((tau-tau0)^-2) decay at every
+            # in-chunk tau; rb_boost == 1 for never-rebooted clients =>
+            # multiplier 1
+            dt = jnp.maximum(tau - rb_tau0, 0).astype(jnp.float32)
+            coeffs = coeffs * (1.0 + (rb_boost - 1.0)
+                               / jnp.square(1.0 + dt))
         eta = jnp.float32(self.eta0) / jnp.maximum(
             (tau + 1 - lr_shift).astype(jnp.float32), 1.0)
         pspecs = (self._param_specs(params) if self.sharding is not None
@@ -766,31 +770,25 @@ class RoundEngine:
                 idxs = fs.put_client(idxs, axis_dim=1)
 
         tel = self.telemetry
-        self._m_spans.inc()
-        self._m_rounds.inc(n_rounds)
-        # optional jax profiler hook: a Telemetry built with
-        # jax_trace_dir= wraps every device dispatch (incl. the Pallas
-        # agg path) in a profiler trace for offline TensorBoard analysis
-        prof = (jax.profiler.trace(tel.jax_trace_dir)
-                if tel.jax_trace_dir else contextlib.nullcontext())
         ms, off, tau = [], 0, tau_start
-        with tel.span("engine.run_span", tau=tau_start,
-                      rounds=n_rounds), prof:
+        with tel.span("engine.run_span", tau=tau_start, rounds=n_rounds):
             for r in _pow2_chunks(n_rounds, self.chunk_size):
                 tau0 = np.int32(tau)     # round indices derive in-jit
-                if plan is not None:
-                    fn = self._get_fn(r, sampled=False)
-                    params, m = fn(params, self.data,
-                                   alphas[off:off + r], idxs[off:off + r],
-                                   tau0, p, rb_tau0, rb_boost, lr_shift)
-                else:
-                    fn = self._get_fn(r, sampled=True)
-                    # the base key passes through unchanged: per-round
-                    # randomness folds tau inside the chunk body, so chunk
-                    # splits never reuse (or re-shuffle) randomness
-                    params, m = fn(params, self.data, self.n,
-                                   self.s_cdf, key, active, tau0, p,
-                                   rb_tau0, rb_boost, lr_shift)
+                with tel.span("engine.dispatch", rounds=r):
+                    if plan is not None:
+                        fn = self._get_fn(r, sampled=False)
+                        params, m = fn(params, self.data,
+                                       alphas[off:off + r],
+                                       idxs[off:off + r],
+                                       tau0, p, rb_tau0, rb_boost, lr_shift)
+                    else:
+                        fn = self._get_fn(r, sampled=True)
+                        # the base key passes through unchanged: per-round
+                        # randomness folds tau inside the chunk body, so
+                        # chunk splits never reuse (or re-shuffle) randomness
+                        params, m = fn(params, self.data, self.n,
+                                       self.s_cdf, key, active, tau0, p,
+                                       rb_tau0, rb_boost, lr_shift)
                 ms.append(jax.tree.map(np.asarray, m) if host_metrics
                           else m)
                 off += r

@@ -119,8 +119,6 @@ class StreamScheduler:
         # own telemetry; a freshly built one inherits the scheduler's
         self.telemetry = resolve_telemetry(telemetry)
         self.observer = FedObserver(self.telemetry)
-        self._m_applied = self.telemetry.counter(
-            "sched_spans_total", "event-free spans executed")
         self._m_cache_hit = self.telemetry.counter(
             "sched_eval_cache_hits_total",
             "eval-array cache hits (objective unchanged)")
@@ -398,6 +396,10 @@ class StreamScheduler:
 
     # -- main loop ------------------------------------------------------------
     def run(self, n_rounds: int, eval_every: int = 1):
+        with self.telemetry.span("sched.run", rounds=n_rounds):
+            return self._run(n_rounds, eval_every)
+
+    def _run(self, n_rounds: int, eval_every: int):
         eng = self.engine
         st = self.state
         start = st.next_tau
@@ -460,7 +462,6 @@ class StreamScheduler:
                         self.params, m = eng.run_span(
                             self.params, tau, R, plan=(alphas, idxs),
                             host_metrics=False, **kwargs)
-                self._m_applied.inc()
                 eval_last = (end - 1) % eval_every == 0 or (ev and R == 1)
                 ev_result = self.evaluate() if eval_last else None
                 pending.append((tau, end, ev, m, ev_result))
@@ -469,7 +470,8 @@ class StreamScheduler:
         finally:
             # materialize whatever completed, even if a mid-run fault
             # unwound the loop — those spans did run
-            self._flush_spans(pending)
+            with self.telemetry.span("sched.flush", spans=len(pending)):
+                self._flush_spans(pending)
         return self.history
 
     def _flush_spans(self, pending) -> None:

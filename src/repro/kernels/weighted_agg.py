@@ -85,6 +85,8 @@ def resolve_interpret(interpret):
 
 
 _HIGHEST = jax.lax.Precision.HIGHEST     # f32 MXU passes, not one bf16
+# the zero pad of the operands, named apart from the reduction in a profile
+PAD_SCOPE = "weighted_agg.pad"
 
 
 def _coeff_slabs(coeffs, Kp: int, k_block: int):
@@ -144,7 +146,8 @@ def weighted_agg(coeffs, deltas, *, block: int = DEFAULT_BLOCK,
     # rows contribute 0)
     pad, kpad = (-D) % block, (-K) % k_block
     if pad or kpad:
-        deltas = jnp.pad(deltas, ((0, kpad), (0, pad)))
+        with jax.named_scope(PAD_SCOPE):
+            deltas = jnp.pad(deltas, ((0, kpad), (0, pad)))
     Kp, Dp = K + kpad, D + pad
     out = pl.pallas_call(
         _agg_kernel,
@@ -212,10 +215,11 @@ def weighted_agg_quant(coeffs, payload, scales, *,
     k_block = _k_block(K, k_block)
     pad_c, kpad = (-n_chunks) % bc, (-K) % k_block
     Kp, n_cp = K + kpad, n_chunks + pad_c
-    s = jnp.pad(scales, ((0, kpad), (0, pad_c)))
-    s = s.reshape(Kp // k_block, k_block, n_cp).transpose(0, 2, 1)
-    if pad_c or kpad:
-        payload = jnp.pad(payload, ((0, kpad), (0, pad_c * chunk)))
+    with jax.named_scope(PAD_SCOPE):
+        s = jnp.pad(scales, ((0, kpad), (0, pad_c)))
+        s = s.reshape(Kp // k_block, k_block, n_cp).transpose(0, 2, 1)
+        if pad_c or kpad:
+            payload = jnp.pad(payload, ((0, kpad), (0, pad_c * chunk)))
     Dp = n_cp * chunk
     out = pl.pallas_call(
         functools.partial(_agg_kernel_quant, chunk=chunk),

@@ -1,12 +1,14 @@
 """repro.obs — the federation telemetry plane.
 
-Zero-dependency (numpy + stdlib) observability for the whole stack:
+Dependency-light (numpy + stdlib, with ``jax.profiler`` annotations)
+observability for the whole stack:
 
   * :mod:`repro.obs.metrics` — thread-safe counters / gauges /
     fixed-bucket histograms in a :class:`MetricsRegistry`, with a
     Prometheus text exposition and a plain-dict snapshot;
   * :mod:`repro.obs.tracing` — monotonic-clock spans on a bounded ring
-    buffer with parent/child nesting and a JSONL exporter;
+    buffer with parent/child nesting and a JSONL exporter, each also a
+    ``jax.profiler.TraceAnnotation`` so it shows in any active profile;
   * :mod:`repro.obs.telemetry` — the :class:`Telemetry` facade every
     constructor accepts (``telemetry=None`` → the shared
     :data:`NULL` no-op);

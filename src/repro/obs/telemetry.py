@@ -9,10 +9,12 @@ finished span's duration is also observed into the
 the JSONL trace describe the same events.
 
 The default everywhere is :data:`NULL`, a ``NullTelemetry`` whose
-metrics are shared no-op singletons and whose ``span()`` returns a
-shared no-op context manager — no allocation, no clock reads, no locks.
-Tier-1 tests pin that a null-telemetry run is bit-identical to an
-uninstrumented one and triggers zero extra recompiles.
+metrics are shared no-op singletons and whose ``span()`` returns only a
+``jax.profiler.TraceAnnotation`` — no ring, no clock read, no lock — so
+the spans still name the host's work in any active JAX profile, and
+with no profiler running each costs one inactive TraceMe.  Tier-1 tests
+pin that a null-telemetry run is bit-identical to an uninstrumented one
+and triggers zero extra recompiles.
 
 Constructors accept ``telemetry=None`` and call :func:`resolve` so the
 null default never needs importing at call sites.
@@ -21,7 +23,8 @@ from __future__ import annotations
 
 import json
 import time
-from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 from .metrics import DEFAULT_BUCKETS, MetricsRegistry
 from .tracing import Tracer
@@ -32,8 +35,7 @@ class Telemetry:
 
     enabled = True
 
-    def __init__(self, span_capacity: int = 4096,
-                 jax_trace_dir: Optional[str] = None):
+    def __init__(self, span_capacity: int = 4096):
         self.registry = MetricsRegistry()
         self._span_seconds = self.registry.histogram(
             "span_seconds", "wall time of traced spans by name",
@@ -42,9 +44,6 @@ class Telemetry:
             capacity=span_capacity,
             on_finish=lambda name, dur:
                 self._span_seconds.labels(name).observe(dur))
-        # when set, RoundEngine.run_span wraps device dispatch in a
-        # jax.profiler trace writing into this directory
-        self.jax_trace_dir = jax_trace_dir
 
     # -- metric / span creation (delegates) -----------------------------------
     def counter(self, name: str, help: str = "", labelnames=()):
@@ -106,19 +105,7 @@ class _NullMetric:
     def buckets(self): return []
 
 
-class _NullSpan:
-    """Shared no-op context manager; also quacks like a Span."""
-    __slots__ = ()
-    name = ""
-    dur_s = 0.0
-    attrs: dict = {}
-
-    def __enter__(self): return self
-    def __exit__(self, *exc): return False
-
-
 _NULL_METRIC = _NullMetric()
-_NULL_SPAN = _NullSpan()
 
 
 class NullTelemetry:
@@ -132,7 +119,6 @@ class NullTelemetry:
     enabled = False
     registry = None
     tracer = None
-    jax_trace_dir = None
 
     def counter(self, name: str, help: str = "", labelnames=()):
         return _NULL_METRIC
@@ -145,7 +131,9 @@ class NullTelemetry:
         return _NULL_METRIC
 
     def span(self, name: str, **attrs):
-        return _NULL_SPAN
+        # profile-only: the span shows in an active JAX profile, and
+        # nothing is recorded here
+        return TraceAnnotation(name, **attrs)
 
     def render_prom(self) -> str:
         return ""

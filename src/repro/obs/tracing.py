@@ -1,4 +1,5 @@
-"""Low-overhead monotonic-clock spans on a bounded ring buffer.
+"""Low-overhead monotonic-clock spans on a bounded ring buffer, mirrored
+into the JAX profiler.
 
 A ``Span`` is a context manager timing one operation::
 
@@ -14,12 +15,18 @@ trace can be reassembled into the call tree.  All timestamps come from
 heartbeat and the recovery MTTR records use, so span timings and
 chaos-report latencies are directly comparable.
 
+Every span also enters a ``jax.profiler.TraceAnnotation`` of the same
+name (its attrs become the event's stats), so whenever a JAX profile is
+being recorded the span appears on the profiler's clock beside the
+device ops it dispatched.  With no profiler running that annotation is
+one inactive TraceMe.
+
 The per-span cost is two clock reads, a couple of attribute writes and
 one deque append under a lock — cheap enough to leave on in production
 spans (the enabled-overhead budget is pinned by
 tests/test_telemetry.py).  The *disabled* path never reaches this
-module: the null telemetry object returns a shared no-op context
-manager instead (obs/telemetry.py).
+module: the null telemetry object returns the profiler annotation alone
+(obs/telemetry.py).
 """
 from __future__ import annotations
 
@@ -29,12 +36,14 @@ import time
 from collections import deque
 from typing import Callable, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 
 class Span:
     """One timed operation; re-entrant use is not supported (make a new
     span per operation — ``Tracer.span`` always does)."""
     __slots__ = ("_tracer", "name", "attrs", "t0", "dur_s", "span_id",
-                 "parent_id")
+                 "parent_id", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -44,8 +53,11 @@ class Span:
         self.dur_s = 0.0
         self.span_id = 0
         self.parent_id: Optional[int] = None
+        self._ann = None
 
     def __enter__(self) -> "Span":
+        self._ann = TraceAnnotation(self.name, **self.attrs)
+        self._ann.__enter__()
         tr = self._tracer
         stack = tr._stack()
         self.parent_id = stack[-1].span_id if stack else None
@@ -59,6 +71,7 @@ class Span:
     def __exit__(self, *exc) -> bool:
         tr = self._tracer
         self.dur_s = tr.clock() - self.t0
+        self._ann.__exit__(*exc)
         stack = tr._stack()
         if stack and stack[-1] is self:
             stack.pop()

@@ -15,6 +15,7 @@ The acceptance-critical properties pinned here:
 import json
 import math
 import time
+import warnings
 
 import jax
 import numpy as np
@@ -156,6 +157,8 @@ def test_null_telemetry_is_inert():
     assert c.value == 0.0
     with tel.span("x", a=1):
         pass
+    # no ring and no registry: the span is a profiler annotation alone
+    assert tel.tracer is None and tel.registry is None
     assert tel.render_prom() == ""
 
 
@@ -208,6 +211,117 @@ def test_enabled_overhead_bounded():
 
     off, on = rps(None), rps(Telemetry())
     assert on >= 0.4 * off, (off, on)
+
+
+# -- profiler alignment --------------------------------------------------------
+
+def _profile_host_events(tmp_path, fn):
+    """Run fn under a JAX profile; the host events as (name, stats)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    with warnings.catch_warnings():
+        # jaxlib's stats iterator warns about its own type on every read
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    out.extend((e.name, dict(e.stats)) for e in line.events)
+    return out
+
+
+def test_null_telemetry_spans_reach_the_profiler(tmp_path):
+    """With telemetry=None the scheduler and engine spans still name the
+    host's work in an active profile: one sched.run per call, one
+    engine.dispatch per chunk (with its rounds), one sched.flush."""
+    sch = make_scheduler(make_clients(4, seed=0), seed=0, chunk_size=4)
+    sch.run(4, eval_every=NO_EVAL)        # compile outside the profile
+    events = _profile_host_events(
+        tmp_path, lambda: sch.run(8, eval_every=NO_EVAL))
+    names = [n for n, _ in events]
+    assert names.count("sched.run") == 1
+    assert names.count("sched.flush") == 1
+    dispatch = [st for n, st in events if n == "engine.dispatch"]
+    assert [st["rounds"] for st in dispatch] == [4, 4]
+    assert {"sched.run_span", "engine.run_span"} <= set(names)
+
+
+def test_telemetry_spans_keep_their_ring_and_reach_the_profiler(tmp_path):
+    tel = Telemetry()
+
+    def work():
+        with tel.span("demo.outer", k=2):
+            with tel.span("demo.inner"):
+                pass
+
+    events = _profile_host_events(tmp_path, work)
+    assert ("demo.outer", {"k": 2}) in events
+    assert "demo.inner" in [n for n, _ in events]
+    assert [s["name"] for s in tel.tracer.peek()] == ["demo.inner",
+                                                      "demo.outer"]
+    h = tel.registry.histogram("span_seconds", labelnames=("name",))
+    assert h.labels("demo.outer").count == 1
+
+
+def _sampled_chunk(**kw):
+    """The engine's sampled 2-round chunk, lowered (not compiled)."""
+    sch = make_scheduler(make_clients(4, seed=0), seed=0, **kw)
+    eng = sch.engine
+    a = sch.state.span_args(0)
+    args = (sch.params, eng.data, eng.n, eng.s_cdf, sch.state.key,
+            np.asarray(a["active"], np.float32), np.int32(0),
+            np.asarray(a["p"], np.float32),
+            np.asarray(a["reboot_tau0"], np.int32),
+            np.asarray(a["reboot_boost"], np.float32), np.int32(0))
+    return eng._get_fn(2, sampled=True).lower(*args)
+
+
+def _sampled_chunk_hlo(**kw):
+    """The lowered chunk as text with its op_name locations."""
+    return _sampled_chunk(**kw).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("kw, scopes", [
+    (dict(agg="flat", interpret=True),
+     {"sample", "gather", "local_sgd", "flatten", "aggregate"}),
+    (dict(agg="flat", interpret=True, compression="int8"),
+     {"sample", "gather", "local_sgd", "flatten", "aggregate"}),
+    (dict(agg="tree"), {"sample", "gather", "local_sgd", "aggregate"}),
+    (dict(engine_mode="client_sequential"),
+     {"sample", "gather", "local_sgd", "aggregate"}),
+], ids=["flat", "flat-int8", "tree", "sequential"])
+def test_round_phases_are_named_scopes(kw, scopes):
+    """Each phase of a round carries its jax.named_scope into the HLO's
+    op_name metadata, on every aggregation layout and mode."""
+    import re
+    hlo = _sampled_chunk_hlo(**kw)
+    assert set(re.findall(r"fed\.([a-z_]+)", hlo)) == scopes
+
+
+@pytest.mark.parametrize("kw", [
+    dict(agg="flat", interpret=True),
+    dict(agg="flat", interpret=True, compression="int8"),
+], ids=["flat", "flat-int8"])
+def test_aggregation_kernel_names_its_operand_pad(kw):
+    """The weighted_agg kernels' zero pad carries the kernel module's own
+    scope, inside the round's fed.aggregate, and no phase name of the
+    round."""
+    import re
+
+    from repro.kernels.weighted_agg import PAD_SCOPE
+    hlo = _sampled_chunk(**kw).compile().as_text()
+    pads = set(re.findall(r'op_name="([^"]*)"', hlo))
+    pads = {n for n in pads if f"/{PAD_SCOPE}/" in n}
+    assert pads and all("/fed.aggregate/jit(weighted_agg" in n
+                        and "fed.flatten" not in n for n in pads)
 
 
 # -- paper gauges (fedmetrics) -------------------------------------------------
