@@ -6,8 +6,10 @@ numpy — every round.  This engine moves the whole round inside one jitted,
 chunked ``lax.scan``:
 
   * client datasets are padded to a common length and live on device once
-    as (C, Nmax, ...) stacks; per-round batch selection is an on-device
-    gather (vmapped ``jnp.take``);
+    as (C, Nmax, ...) stacks, a sample of rank >= 2 flattened to one
+    lane-dense row (BufferSpec.row_shape); per-round batch selection is an
+    on-device gather of whole rows (vmapped ``jnp.take``), and the loss
+    takes each sample's shape back per local step;
   * participation masks alpha can be sampled on device (inverse-CDF draw
     from an exact per-client table of the paper's Table-2 trace law, see
     trace_s_cdf) or supplied as a host-precomputed *plan* — the plan path
@@ -58,6 +60,7 @@ import numpy as np
 from repro.core.aggregation import scheme_coefficients
 from repro.core.compression import resolve_compression, wire_bytes
 from repro.core.fed_step import fed_round_parallel, fed_round_sequential
+from repro.fed.bank import pad_rows
 from repro.fed.task import ArrayTask
 from repro.obs.telemetry import resolve as resolve_telemetry
 
@@ -160,6 +163,19 @@ def device_sample_span(key, R: int, active, n, s_cdf, E: int, B: int):
     keys = jax.vmap(lambda t: jax.random.fold_in(key, t))(jnp.arange(R))
     return jax.vmap(
         lambda k: device_sample_round(k, active, n, s_cdf, E, B))(keys)
+
+
+def gather_rows(data, idx):
+    """The round's batch gather: ``{name: (C, E, B, *row_shape)}`` with
+    ``rows[c, e, b] = data[name][c, idx[c, e, b]]``.  Whole rows per
+    index, vmapped over the slot axis so a sharded slot axis stays
+    shard-local (merging (capacity, Nmax) into one axis would copy the
+    stack whenever Nmax % 8 != 0).  Indices are in [0, n) by
+    construction; ``mode="clip"`` states that, which drops the NaN-fill
+    select jnp.take's default puts after the gather (a relayout pass
+    of the whole batch on the TPU)."""
+    take = jax.vmap(lambda d, i: jnp.take(d, i, axis=0, mode="clip"))
+    return {name: take(buf, idx) for name, buf in data.items()}
 
 
 def _slot_write(buf, row, slot):
@@ -313,7 +329,6 @@ class RoundEngine:
             task = ArrayTask(loss_fn,
                              np.asarray(clients[0].x).shape[1:])
         self.task = task
-        self.loss_fn = task.loss_fn
         if mode not in ("client_parallel", "client_sequential"):
             raise ValueError(f"mode must be client_parallel|"
                              f"client_sequential, got {mode!r}")
@@ -363,7 +378,8 @@ class RoundEngine:
             nmax = max(nmax, max_samples)
         self.nmax = nmax
         # per-sample buffers are the task's business: one (capacity, Nmax,
-        # *spec.shape) stack per named buffer (logreg: x/y; LM: tokens)
+        # *spec.row_shape) stack per named buffer (logreg: x/y; MNIST: x
+        # as 784-wide rows; LM: tokens), filled through its spec.shape view
         stacks = {
             name: np.zeros((capacity, nmax) + spec.shape, spec.dtype)
             for name, spec in task.buffers.items()}
@@ -372,8 +388,8 @@ class RoundEngine:
         n_arr = np.ones(capacity, np.int32)
         cdf = np.tile(empty_slot_cdf(self.E), (capacity, 1))
         for i, c in enumerate(clients):
-            for name, arr in self._client_rows(c).items():
-                stacks[name][i, :c.n] = arr
+            for name, row in pad_rows(task, nmax, c).items():
+                stacks[name][i] = row
             n_arr[i] = c.n
         cdf[:C] = trace_s_cdf(clients, self.E)
         # datasets move host->device exactly once, here; under sharding
@@ -385,7 +401,7 @@ class RoundEngine:
                 a, sharding.replicated())
         else:
             self._put_slots = self._put_row = jax.device_put
-        self.data = {name: self._put_slots(buf)
+        self.data = {name: self._put_slots(self._as_rows(name, buf))
                      for name, buf in stacks.items()}
         self.n = self._put_slots(n_arr)
         self.s_cdf = self._put_slots(cdf)
@@ -409,18 +425,11 @@ class RoundEngine:
             "client->server delta bytes (analytic, by wire format)",
             labelnames=("wire",))
         self._d_total: Optional[int] = None
-
-    def _client_rows(self, client):
-        """The task's per-sample arrays for one client, shape-checked
-        against the engine's buffer specs."""
-        arrays = self.task.client_arrays(client)
-        for name, arr in arrays.items():
-            spec = self.task.buffers[name]
-            if arr.shape != (client.n,) + spec.shape:
-                raise ValueError(
-                    f"feature shape {arr.shape[1:]} != engine feature "
-                    f"shape {spec.shape} (buffer {name!r})")
-        return arrays
+        # build-time gauge: buffers stored as flattened rows
+        tel.gauge("engine_lane_dense_buffers",
+                  "per-sample buffers stored as flattened rows").set(
+            sum(spec.row_shape != spec.shape
+                for spec in task.buffers.values()))
 
     def _param_specs(self, params):
         """The task's per-leaf PartitionSpecs (None => replicated),
@@ -457,14 +466,26 @@ class RoundEngine:
             self._pspecs_built = True
         return self._pspecs
 
-    # legacy buffer aliases (pre-ClientTask layout)
-    @property
-    def data_x(self):
-        return self.data["x"]
+    def _as_rows(self, name, stack):
+        """A host (..., Nmax, *spec.shape) stack viewed in the device
+        layout (..., Nmax, *spec.row_shape): a reshape of a contiguous
+        array, so it copies nothing."""
+        spec = self.task.buffers[name]
+        lead = stack.ndim - len(spec.shape)
+        return stack.reshape(stack.shape[:lead] + spec.row_shape)
 
-    @property
-    def data_y(self):
-        return self.data["y"]
+    def _step_loss(self, params, rows):
+        """The task's loss on one local step's gathered rows: each
+        buffer takes its per-sample shape back and ``make_batch`` runs
+        here, per step, so no relaid copy of the round's batch sits
+        between the gather and the loss (for the MLP the reshape folds
+        into its own flatten)."""
+        batch = {}
+        for name, r in rows.items():
+            spec = self.task.buffers[name]
+            lead = r.ndim - len(spec.row_shape)
+            batch[name] = r.reshape(r.shape[:lead] + spec.shape)
+        return self.task.loss_fn(params, self.task.make_batch(batch))
 
     # -- capacity-slot lifecycle ----------------------------------------------
     def admit(self, slot: int, client) -> None:
@@ -477,21 +498,6 @@ class RoundEngine:
             raise IndexError(f"slot {slot} out of range [0, {self.capacity})")
         with self.telemetry.span("engine.admit", slot=slot):
             self._admit_many([(slot, client)])
-
-    def _staged_rows(self, client):
-        """Zero-padded (Nmax, *spec.shape) rows for every task buffer."""
-        if client.n > self.nmax:
-            raise ValueError(
-                f"client has {client.n} samples > slot capacity "
-                f"{self.nmax}; build the engine with max_samples >= "
-                f"{client.n}")
-        rows = {}
-        for name, arr in self._client_rows(client).items():
-            spec = self.task.buffers[name]
-            row = np.zeros((self.nmax,) + spec.shape, spec.dtype)
-            row[:client.n] = arr
-            rows[name] = row
-        return rows
 
     def admit_many(self, assignments) -> None:
         """Admit an arrival burst in ONE fused device dispatch.
@@ -512,7 +518,7 @@ class RoundEngine:
         with self.telemetry.span("engine.admit_many", k=len(assignments)):
             self._admit_many(assignments)
 
-    def _admit_many(self, assignments, rows_of=None) -> None:
+    def _admit_many(self, assignments) -> None:
         for slot, _ in assignments:
             if not 0 <= slot < self.capacity:
                 raise IndexError(
@@ -522,8 +528,7 @@ class RoundEngine:
             # duplicate-index scatter order is unspecified per buffer, so
             # one slot could mix two clients' rows across buffers
             raise ValueError(f"admit_many got duplicate slots: {dup}")
-        rows_of = rows_of or self._staged_rows
-        staged = [rows_of(c) for _, c in assignments]
+        staged = [pad_rows(self.task, self.nmax, c) for _, c in assignments]
         k = len(assignments)
         pad = _pow2_pad(k) - k
         stacks = {name: np.stack([st[name] for st in staged]
@@ -538,11 +543,12 @@ class RoundEngine:
     # -- staged-cohort handoff (fed/bank.CohortStager) ------------------------
     def put_burst(self, stacks) -> dict:
         """Move pre-stacked (k, Nmax, *spec.shape) host buffers to device
-        (replicated under sharding).  Pure transfer, no engine mutation —
-        safe to call from a staging thread while a span runs.  All
-        buffers go up in ONE batched device_put — per-buffer puts cost
-        a host dispatch each."""
-        host = {name: np.ascontiguousarray(a) for name, a in stacks.items()}
+        as (k, Nmax, *spec.row_shape) rows (replicated under sharding).
+        Pure transfer, no engine mutation — safe to call from a staging
+        thread while a span runs.  All buffers go up in ONE batched
+        device_put — per-buffer puts cost a host dispatch each."""
+        host = {name: self._as_rows(name, np.ascontiguousarray(a))
+                for name, a in stacks.items()}
         if self.sharding is not None:
             return jax.device_put(host, self.sharding.replicated())
         return jax.device_put(host)
@@ -551,7 +557,7 @@ class RoundEngine:
         """Land a (possibly prefetched) burst: one fused jitted
         gather+scatter across every data buffer plus n and s_cdf.
 
-        dev_rows: put_burst output — (K, Nmax, *spec.shape) device
+        dev_rows: put_burst output — (K, Nmax, *spec.row_shape) device
         stacks; slots/ns/cdfs: per-written-slot values in slot order;
         idx: row index into dev_rows for each written slot (default
         identity), so a staged cohort can be committed as a subset or
@@ -621,9 +627,9 @@ class RoundEngine:
         # HLO's op_name metadata, so a device profile attributes its ops
         # to them; the compiled program is otherwise unchanged
         with jax.named_scope("fed.gather"):
-            gather = jax.vmap(lambda d, i: jnp.take(d, i, axis=0))
-            batches = self.task.make_batch(
-                {name: gather(buf, idx) for name, buf in data.items()})
+            # the (C, E, B, *row_shape) rows reach the loss as they are
+            # (_step_loss)
+            rows = gather_rows(data, idx)
         with jax.named_scope("fed.aggregate"):
             s = jnp.sum(alpha, axis=-1)
             coeffs = scheme_coefficients(self.scheme, p, s, self.E)
@@ -646,7 +652,7 @@ class RoundEngine:
                 "compression='none'")
         if self.mode == "client_sequential":
             new_params, m = fed_round_sequential(
-                self.loss_fn, params, batches, alpha, coeffs, eta,
+                self._step_loss, params, rows, alpha, coeffs, eta,
                 with_metrics=self.with_metrics, sharding=self.sharding,
                 param_specs=pspecs, compression=self.compression)
         else:
@@ -656,7 +662,7 @@ class RoundEngine:
             # reduced (D_total,) vector replicated over the model axes
             agg = "tree" if pspecs is not None else self.agg
             new_params, m = fed_round_parallel(
-                self.loss_fn, params, batches, alpha, coeffs, eta,
+                self._step_loss, params, rows, alpha, coeffs, eta,
                 agg=agg, interpret=self.interpret,
                 with_metrics=self.with_metrics, sharding=self.sharding,
                 param_specs=pspecs, compression=self.compression)

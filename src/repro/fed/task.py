@@ -37,6 +37,7 @@ Usage::
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
 
@@ -49,9 +50,20 @@ __all__ = ["BufferSpec", "ClientTask", "ArrayTask", "LMTask"]
 @dataclass(frozen=True)
 class BufferSpec:
     """One per-sample device-resident buffer: the engine stores it as a
-    ``(capacity, Nmax) + shape`` stack of the given dtype."""
+    ``(capacity, Nmax) + row_shape`` stack of the given dtype."""
     shape: Tuple[int, ...]
     dtype: Any = np.float32
+
+    @property
+    def row_shape(self) -> Tuple[int, ...]:
+        """A sample's shape in the engine's device stack.  A sample of
+        rank >= 2 (an image, an audio token block) is one row of
+        ``prod(shape)`` elements, so the round's batch gather moves whole
+        lane-dense rows and the loss takes the shape back per step;
+        rank <= 1 samples are rows already."""
+        if len(self.shape) < 2:
+            return self.shape
+        return (math.prod(self.shape),)
 
 
 class ClientTask:
@@ -61,8 +73,9 @@ class ClientTask:
     buffers          — dict name -> BufferSpec of per-sample arrays.
     loss_fn(p, b)    — scalar training loss on one batch.
     client_arrays(c) — dict name -> (n, *spec.shape) arrays for a Client.
-    make_batch(g)    — map gathered buffers (each (..., B) + spec.shape,
-                       any leading dims) to the loss_fn batch pytree.
+    make_batch(g)    — map one local step's gathered buffers (each
+                       (..., B) + spec.shape) to the loss_fn batch pytree;
+                       the engine calls it inside the loss, per step.
     init_params(key) — fresh parameter pytree.
     param_specs(p)   — pytree of PartitionSpec matching params, or None
                        to replicate (small models).  Specs may name mesh

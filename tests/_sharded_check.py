@@ -18,8 +18,13 @@ Checks (the acceptance criteria of the sharded federation axis):
      sharding (compile-cache entry counts are flat);
   5. null-vs-enabled telemetry on the *sharded* engine: bit-identical
      history and params, identical trace counts (the single-device
-     pin lives in tests/test_telemetry.py).
+     pin lives in tests/test_telemetry.py);
+  6. the batch gather stays slot-local: a 28x28 MLP engine's compiled
+     chunk holds no collective (all-gather, all-reduce, ...) of a
+     (slot, sample, 784) row stack, of its label stack or of gathered
+     784-wide rows.
 """
+import re  # noqa: E402
 import os
 
 os.environ.setdefault("XLA_FLAGS",
@@ -170,6 +175,40 @@ def check_null_telemetry(fs):
     RESULTS["null_telemetry_trace_count"] = int(a.engine.trace_count)
 
 
+def check_gather_slot_local(fs):
+    from repro.configs.paper import PaperModelConfig
+    from repro.fed import RoundEngine
+
+    cfg = PaperModelConfig(name="mlp28", kind="mlp", input_shape=(28, 28),
+                           n_classes=10, hidden=16)
+    rng = np.random.default_rng(0)
+    nmax = 61                            # not a multiple of 8
+    clients = [Client(x=rng.standard_normal((n, 28, 28)).astype(np.float32),
+                      y=rng.integers(0, 10, n).astype(np.int32),
+                      trace=TRACES[i]) for i, n in enumerate([61, 30, 17])]
+    eng = RoundEngine(loss_fn=make_loss_fn(cfg), clients=clients,
+                      local_epochs=2, batch_size=4, capacity=7,
+                      sharding=fs, agg="tree")
+    cap = eng.capacity
+    assert eng.data["x"].shape == (cap, nmax, 784), eng.data["x"].shape
+    ones = np.ones(cap, np.float32)
+    args = fs.put_client(ones)
+    hlo = eng._get_fn(2, sampled=True).lower(
+        init_small(jax.random.PRNGKey(0), cfg), eng.data, eng.n, eng.s_cdf,
+        jax.random.PRNGKey(1), args, np.int32(0), args,
+        fs.put_client(np.zeros(cap, np.int32)), args,
+        np.int32(0)).compile().as_text()
+    data = re.compile(rf"\[(?:{cap}|{cap // fs.n_shards}),{nmax}[,\]]"
+                      r"|\[[0-9,]+,784\]")
+    colls = [ln for ln in hlo.splitlines() if re.search(
+        r"\b(all-gather|all-reduce|all-to-all|collective-permute|"
+        r"reduce-scatter)(-start)?\(", ln)]
+    assert colls, "expected the aggregation's all-reduce"
+    bad = [ln for ln in colls if data.search(ln)]
+    assert not bad, bad[:2]
+    RESULTS["data_collectives"] = len(bad)
+
+
 def main():
     n_dev = len(jax.devices())
     assert n_dev == 4, f"expected 4 virtual devices, got {n_dev}"
@@ -180,6 +219,7 @@ def main():
     check_device_sampling_invariance(fs)
     check_zero_recompile_churn(fs)
     check_null_telemetry(fs)
+    check_gather_slot_local(fs)
     RESULTS["n_devices"] = n_dev
     _subproc.emit(RESULTS)
 

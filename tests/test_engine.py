@@ -18,13 +18,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.paper import SYNTHETIC_LR
+from repro.configs.paper import SYNTHETIC_LR, PaperModelConfig
 from repro.core.aggregation import (aggregate_deltas, aggregate_deltas_flat,
-                                    accumulate_delta)
+                                    accumulate_delta, scheme_coefficients)
+from repro.core.fed_step import fed_round_parallel, fed_round_sequential
 from repro.core.participation import TRACES
 from repro.data import synthetic_federation
-from repro.fed import Client, FederatedTrainer, RoundEngine
-from repro.fed.engine import _pow2_chunks, trace_s_cdf
+from repro.fed import Client, FederatedTrainer, LMTask, RoundEngine
+from repro.fed.engine import (_pow2_chunks, device_sample_round,
+                              gather_rows, trace_s_cdf)
+from repro.fed.task import BufferSpec, ClientTask
 from repro.models.small import init_small, logits_small, make_loss_fn
 
 CFG = SYNTHETIC_LR
@@ -309,3 +312,200 @@ def test_trace_s_cdf_properties():
             assert cdf[i, 0] == 0.0                   # s >= 1 clamp
         else:
             assert cdf[i, 0] >= c.trace.p_inactive - 1e-6
+
+
+# -- lane-dense row store: per-sample buffers as (slot, sample, F) rows -------
+
+MLP28 = PaperModelConfig(name="mlp28", kind="mlp", input_shape=(28, 28),
+                         n_classes=10, hidden=16)
+
+
+def old_gather(stacks, idx):
+    """The gather before the row store: vmapped take over (capacity, Nmax)
+    + spec.shape stacks."""
+    take = jax.vmap(lambda d, i: jnp.take(d, i, axis=0))
+    return {name: take(buf, idx) for name, buf in stacks.items()}
+
+
+def old_stacks(task, clients, capacity, nmax):
+    """(capacity, Nmax) + spec.shape host stacks, empty slots zero."""
+    stacks = {name: np.zeros((capacity, nmax) + spec.shape, spec.dtype)
+              for name, spec in task.buffers.items()}
+    for i, c in enumerate(clients):
+        for name, arr in task.client_arrays(c).items():
+            stacks[name][i, :c.n] = arr
+    return stacks
+
+
+class OneBuffer(ClientTask):
+    """A task with one per-sample buffer of any shape (gather tests)."""
+
+    def __init__(self, shape, dtype):
+        self.buffers = {"v": BufferSpec(tuple(shape), dtype)}
+
+    def client_arrays(self, client):
+        return {"v": client.x}
+
+
+def mlp28_clients(sizes, seed=0):
+    rng = np.random.default_rng(seed)
+    return [Client(x=rng.standard_normal((n, 28, 28)).astype(np.float32),
+                   y=rng.integers(0, 10, n).astype(np.int32),
+                   trace=TRACES[i % 8]) for i, n in enumerate(sizes)]
+
+
+@pytest.mark.parametrize("shape, dtype", [
+    ((28, 28), np.float32), ((784,), np.float32), ((), np.int32),
+    ((9,), np.int32), ((9, 2), np.int32)],
+    ids=["28x28", "784", "scalar", "tokens", "codebooks"])
+def test_row_store_gather_matches_old_gather(shape, dtype):
+    """The row store's gather returns exactly the samples of the old
+    vmap(take) over (capacity, Nmax) + shape stacks: Nmax 13 (not a
+    multiple of 8), two empty slots (n = 1) gathered at index 0."""
+    rng = np.random.default_rng(3)
+    sizes = [13, 5, 9, 1]
+    clients = [Client(x=(rng.standard_normal((n,) + shape) * 50).astype(
+        dtype), trace=TRACES[i]) for i, n in enumerate(sizes)]
+    task = OneBuffer(shape, dtype)
+    eng = RoundEngine(task=task, clients=clients, local_epochs=3,
+                      batch_size=4, capacity=6, max_samples=13)
+    row_shape = (int(np.prod(shape)),) if len(shape) >= 2 else shape
+    assert eng.data["v"].shape == (6, 13) + row_shape
+    _, idx = device_sample_round(jax.random.PRNGKey(5),
+                                 jnp.ones(6, jnp.float32), eng.n,
+                                 eng.s_cdf, 3, 4)
+    got = np.asarray(gather_rows(eng.data, idx)["v"])
+    want = np.asarray(old_gather(old_stacks(task, clients, 6, 13), idx)["v"])
+    assert got.shape == (6, 3, 4) + row_shape
+    np.testing.assert_array_equal(got.reshape(want.shape), want)
+    assert (np.asarray(idx)[4:] == 0).all()      # the empty slots
+
+
+def _reference_span(mode, loss_fn, params, stacks, eng, key, p, R):
+    """R rounds of fed_round_parallel / fed_round_sequential fed with
+    batches from the old gather, under the engine's own draws."""
+    active = jnp.ones(eng.capacity, jnp.float32)
+
+    def body(w, tau):
+        alpha, idx = device_sample_round(jax.random.fold_in(key, tau),
+                                         active, eng.n, eng.s_cdf,
+                                         eng.E, eng.B)
+        batches = old_gather(stacks, idx)
+        coeffs = scheme_coefficients("C", p, jnp.sum(alpha, -1), eng.E)
+        eta = jnp.float32(eng.eta0) / jnp.maximum(
+            (tau + 1).astype(jnp.float32), 1.0)
+        if mode == "client_parallel":
+            w, _ = fed_round_parallel(loss_fn, w, batches, alpha, coeffs,
+                                      eta, agg="tree", with_metrics=False)
+        else:
+            w, _ = fed_round_sequential(loss_fn, w, batches, alpha, coeffs,
+                                        eta, with_metrics=False)
+        return w, None
+
+    return jax.jit(lambda w: jax.lax.scan(
+        body, w, jnp.arange(R, dtype=jnp.int32))[0])(params)
+
+
+@pytest.mark.parametrize("mode", ["client_parallel", "client_sequential"])
+def test_row_store_engine_bit_identical_to_old_gather(mode):
+    """A 16-round span on a 28x28 MLP federation gives the parameters of
+    the round functions fed by the old gather, bit for bit on the CPU;
+    the x buffer is the one stored as rows."""
+    from repro.obs.telemetry import Telemetry
+
+    clients = mlp28_clients([30, 21, 45, 12])
+    loss_fn = make_loss_fn(MLP28)
+    tel = Telemetry()
+    eng = RoundEngine(loss_fn=loss_fn, clients=clients, local_epochs=3,
+                      batch_size=5, scheme="C", eta0=0.1, chunk_size=16,
+                      capacity=5, max_samples=45, mode=mode, agg="tree",
+                      telemetry=tel)
+    assert tel.registry.gauge("engine_lane_dense_buffers").value == 1
+    assert eng.data["x"].shape == (5, 45, 784)
+    params = init_small(jax.random.PRNGKey(1), MLP28)
+    n = np.array([c.n for c in clients] + [0], np.float64)
+    p = jnp.asarray(n / n.sum(), jnp.float32)
+    key = jax.random.PRNGKey(11)
+    got, _ = eng.run_span(params, 0, 16, p=p, active=np.ones(5, np.float32),
+                          lr_shift_tau=0, reboot_tau0=np.zeros(5, np.int32),
+                          reboot_boost=np.ones(5, np.float32), key=key)
+    stacks = jax.device_put(old_stacks(eng.task, clients, 5, 45))
+    want = _reference_span(mode, loss_fn, params, stacks, eng, key, p, 16)
+    assert_params_close(got, want, rtol=0, atol=0)
+
+
+def test_lane_dense_gauge_reads_zero_for_token_rows():
+    """LM token streams (S+1,) are rows already: nothing is reshaped."""
+    from repro.configs import get_config
+    from repro.obs.telemetry import Telemetry
+
+    task = LMTask(get_config("mamba2-130m").reduced(), seq_len=8)
+    tel = Telemetry()
+    rng = np.random.default_rng(0)
+    clients = [Client(x=task.token_stream(rng, n=3, domain=d),
+                      trace=TRACES[d]) for d in range(2)]
+    eng = RoundEngine(task=task, clients=clients, local_epochs=1,
+                      batch_size=2, telemetry=tel)
+    assert tel.registry.gauge("engine_lane_dense_buffers").value == 0
+    assert eng.data["tokens"].shape == (2, 3, 9)
+
+
+@pytest.mark.parametrize("path", ["admit", "admit_many", "commit_burst",
+                                  "stager"])
+def test_admit_paths_write_rows_without_retrace(path):
+    """Every way a client reaches a slot lands its (Nmax, 28, 28) host
+    rows in the row store: the next round gathers client.x[idx] as
+    784-wide rows, and no chunk function retraces."""
+    from repro.fed.bank import CohortStager, pad_rows
+
+    founding = mlp28_clients([20, 14])
+    fresh = mlp28_clients([17, 9, 20], seed=9)
+    eng = RoundEngine(loss_fn=make_loss_fn(MLP28), clients=founding,
+                      local_epochs=2, batch_size=4, chunk_size=2,
+                      capacity=5, max_samples=20, agg="tree")
+    params = init_small(jax.random.PRNGKey(0), MLP28)
+    span = dict(p=np.full(5, 0.2, np.float32),
+                active=np.ones(5, np.float32), lr_shift_tau=0,
+                reboot_tau0=np.zeros(5, np.int32),
+                reboot_boost=np.ones(5, np.float32))
+    key = jax.random.PRNGKey(2)
+    params, _ = eng.run_span(params, 0, 2, key=key, **span)
+    traces = eng.trace_count
+    slots = [2, 3, 4]
+    if path == "admit":
+        for s, c in zip(slots, fresh):
+            eng.admit(s, c)
+    elif path == "admit_many":
+        eng.admit_many(list(zip(slots, fresh)))
+    elif path == "commit_burst":
+        # staged in reverse order, committed through a reordering idx
+        staged = [pad_rows(eng.task, eng.nmax, c) for c in fresh[::-1]]
+        assert staged[0]["x"].shape == (20, 28, 28)
+        dev = eng.put_burst({name: np.stack([r[name] for r in staged]
+                                            + [staged[-1][name]])
+                             for name in eng.task.buffers})
+        eng.commit_burst(dev, slots=slots, ns=[c.n for c in fresh],
+                         cdfs=[trace_s_cdf([c], eng.E)[0] for c in fresh],
+                         idx=[2, 1, 0])
+    else:
+        stager = CohortStager(eng)
+        stager.submit([(None, c) for c in fresh])
+        cohort = stager.collect()
+        stager.close()
+        assert cohort is not None and cohort.k == 3
+        eng.commit_burst(cohort.dev, slots=slots, ns=[c.n for c in fresh],
+                         cdfs=[trace_s_cdf([c], eng.E)[0] for c in fresh],
+                         idx=[cohort.index[id(c)] for c in fresh])
+    assert eng.data["x"].shape == (5, 20, 784)
+    _, idx = device_sample_round(jax.random.fold_in(key, 2),
+                                 jnp.ones(5, jnp.float32), eng.n, eng.s_cdf,
+                                 eng.E, eng.B)
+    rows = np.asarray(gather_rows(eng.data, idx)["x"])
+    idx = np.asarray(idx)
+    for s, c in zip(slots, fresh):
+        np.testing.assert_array_equal(rows[s].reshape(-1, 784),
+                                      c.x[idx[s].ravel()].reshape(-1, 784))
+    params, _ = eng.run_span(params, 2, 2, key=key, **span)
+    assert eng.trace_count == traces
+    assert all(np.isfinite(np.asarray(x)).all()
+               for x in jax.tree.leaves(params))

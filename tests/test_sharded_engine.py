@@ -149,3 +149,9 @@ def test_sharded_null_telemetry_bit_identity(sharded_check):
     # covers the shard_map'd span path
     assert sharded_check["null_telemetry_bit_identical"] is True
     assert sharded_check["null_telemetry_trace_count"] > 0
+
+
+def test_sharded_row_gather_stays_slot_local(sharded_check):
+    # a 28x28 MLP's (slot, sample, 784) row store: no collective of the
+    # stack or of gathered rows in the compiled chunk
+    assert sharded_check["data_collectives"] == 0
