@@ -425,11 +425,21 @@ class RoundEngine:
             "client->server delta bytes (analytic, by wire format)",
             labelnames=("wire",))
         self._d_total: Optional[int] = None
-        # build-time gauge: buffers stored as flattened rows
+        # completed local steps, summed over client-rounds as their s
+        # reaches the host (account_uploads): tokens trained are this
+        # times engine_tokens_per_step
+        self._m_steps = tel.counter(
+            "fed_local_steps_applied_total",
+            "local SGD steps applied (sum of s over client-rounds)")
+        # build-time gauges: buffers stored as flattened rows; tokens one
+        # client's local step trains (B x seq_len, 0 for non-LM tasks)
         tel.gauge("engine_lane_dense_buffers",
                   "per-sample buffers stored as flattened rows").set(
             sum(spec.row_shape != spec.shape
                 for spec in task.buffers.values()))
+        tel.gauge("engine_tokens_per_step",
+                  "tokens one client's local step trains").set(
+            self.B * task.tokens_per_sample)
 
     def _param_specs(self, params):
         """The task's per-leaf PartitionSpecs (None => replicated),
@@ -807,9 +817,10 @@ class RoundEngine:
 
     def account_uploads(self, s: np.ndarray) -> None:
         """Charge fed_wire_bytes_total for a span's completed-epoch
-        matrix — one delta upload per client-round with any epochs
-        (run_span does this inline; deferred-metrics callers do it at
-        conversion time)."""
+        matrix — one delta upload per client-round with any epochs — and
+        fed_local_steps_applied_total with its sum (run_span does this
+        inline; deferred-metrics callers do it at conversion time)."""
+        self._m_steps.inc(float(np.sum(s)))
         uploads = int((s > 0).sum())
         if uploads:
             self._m_wire.labels(self.compression.name).inc(

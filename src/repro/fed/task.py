@@ -81,9 +81,11 @@ class ClientTask:
                        to replicate (small models).  Specs may name mesh
                        axes that don't exist on a given mesh; they are
                        filtered per-mesh at placement time.
+    tokens_per_sample — tokens one sample trains (0 for non-LM tasks).
     """
 
     buffers: Dict[str, BufferSpec] = {}
+    tokens_per_sample: int = 0
 
     def loss_fn(self, params, batch):
         raise NotImplementedError
@@ -144,6 +146,7 @@ class LMTask(ClientTask):
     def __init__(self, cfg, *, seq_len: int = 128, fsdp: bool = True):
         self.cfg = cfg
         self.seq_len = int(seq_len)
+        self.tokens_per_sample = self.seq_len
         self.fsdp = fsdp
         tail: Tuple[int, ...] = (self.seq_len + 1,)
         if cfg.n_codebooks:

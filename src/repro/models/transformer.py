@@ -27,18 +27,19 @@ LOSS_CHUNK = 512
 
 
 def embed_tokens(params, cfg: ArchConfig, tokens, positions):
-    if cfg.n_codebooks:
-        # tokens: (B,S,K) — summed codebook embeddings
-        parts = [jnp.take(params["embed"][k], tokens[..., k], axis=0)
-                 for k in range(cfg.n_codebooks)]
-        x = sum(parts)
-    else:
-        x = jnp.take(params["embed"], tokens, axis=0)
-    if cfg.embed_scale:
-        x = x * jnp.sqrt(float(cfg.d_model)).astype(x.dtype)
-    if cfg.pos_emb == "sinusoidal":
-        x = x + sinusoidal(positions, cfg.d_model).astype(x.dtype)
-    return constrain(x, P(BATCH, None, None))
+    with jax.named_scope("lm.embed"):
+        if cfg.n_codebooks:
+            # tokens: (B,S,K) — summed codebook embeddings
+            parts = [jnp.take(params["embed"][k], tokens[..., k], axis=0)
+                     for k in range(cfg.n_codebooks)]
+            x = sum(parts)
+        else:
+            x = jnp.take(params["embed"], tokens, axis=0)
+        if cfg.embed_scale:
+            x = x * jnp.sqrt(float(cfg.d_model)).astype(x.dtype)
+        if cfg.pos_emb == "sinusoidal":
+            x = x + sinusoidal(positions, cfg.d_model).astype(x.dtype)
+        return constrain(x, P(BATCH, None, None))
 
 
 def logits_fn(params, cfg: ArchConfig, h):
@@ -142,7 +143,8 @@ def model_forward(params, cfg: ArchConfig, tokens, *, patch_emb=None,
         # leave the sequence-sharded domain before the (token-chunked,
         # vocab-sharded) loss — avoids GSPMD resharding thrash there
         x = constrain(x, P(BATCH, None, None))
-    x = apply_norm(x, params["final_norm"], cfg)
+    with jax.named_scope("lm.head"):
+        x = apply_norm(x, params["final_norm"], cfg)
     return x, total_aux, new_cache
 
 
@@ -166,33 +168,38 @@ def _xent_chunk(params, cfg, h_chunk, labels_chunk):
 
 def chunked_xent(params, cfg, hidden2d, labels1d, chunk=LOSS_CHUNK):
     """hidden2d: (T,d); labels1d: (T[,K]).  -1 labels are masked.
+    Its ops (logits and cross-entropy) carry the device scope
+    ``lm.head``, as does the final norm in ``model_forward``.
 
     §Perf: T is PADDED up to a chunk multiple (masked labels) rather than
     shrinking the chunk — an off-by-one T (e.g. the MTP head's S-1 tokens)
     previously degenerated to 64-token chunks and a 4095-trip loss scan.
     """
-    T = hidden2d.shape[0]
-    c = min(chunk, T)
-    pad = (-T) % c
-    if pad:
-        hidden2d = jnp.pad(hidden2d, ((0, pad),) + ((0, 0),) * (hidden2d.ndim - 1))
-        labels1d = jnp.pad(labels1d, ((0, pad),) + ((0, 0),) * (labels1d.ndim - 1),
-                           constant_values=-1)
-        T += pad
-    nc = T // c
-    hs = hidden2d.reshape(nc, c, -1)
-    ls = labels1d.reshape(nc, c, *labels1d.shape[1:])
+    with jax.named_scope("lm.head"):
+        T = hidden2d.shape[0]
+        c = min(chunk, T)
+        pad = (-T) % c
+        if pad:
+            hidden2d = jnp.pad(
+                hidden2d, ((0, pad),) + ((0, 0),) * (hidden2d.ndim - 1))
+            labels1d = jnp.pad(
+                labels1d, ((0, pad),) + ((0, 0),) * (labels1d.ndim - 1),
+                constant_values=-1)
+            T += pad
+        nc = T // c
+        hs = hidden2d.reshape(nc, c, -1)
+        ls = labels1d.reshape(nc, c, *labels1d.shape[1:])
 
-    def body(carry, xs):
-        s, n = carry
-        h, l = xs
-        ds, dn = _xent_chunk(params, cfg, h, l)
-        return (s + ds, n + dn), None
+        def body(carry, xs):
+            s, n = carry
+            h, l = xs
+            ds, dn = _xent_chunk(params, cfg, h, l)
+            return (s + ds, n + dn), None
 
-    body = jax.checkpoint(body)
-    (s, n), _ = jax.lax.scan(body, (jnp.float32(0.0), jnp.float32(0.0)),
-                             (hs, ls))
-    return s / jnp.maximum(n, 1.0)
+        body = jax.checkpoint(body)
+        (s, n), _ = jax.lax.scan(body, (jnp.float32(0.0), jnp.float32(0.0)),
+                                 (hs, ls))
+        return s / jnp.maximum(n, 1.0)
 
 
 def train_loss(params, cfg: ArchConfig, batch):
