@@ -107,6 +107,9 @@ class ArchConfig:
     ssm_n_groups: int = 1
     ssm_d_conv: int = 4
     ssm_chunk: int = 256
+    # the SSD as the fused Pallas scan (kernels/ssd_chunk) where its shapes
+    # fit; set for a round placed on one device (fed.task.LMTask.placed)
+    ssm_kernel: bool = False
     # --- multimodal stub frontends ---
     n_patches: int = 0             # vlm: patch embeddings prepended
     n_codebooks: int = 0           # audio: EnCodec codebooks (summed embed)

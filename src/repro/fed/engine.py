@@ -328,7 +328,9 @@ class RoundEngine:
                                  "client (fixes the feature shape)")
             task = ArrayTask(loss_fn,
                              np.asarray(clients[0].x).shape[1:])
-        self.task = task
+        # the task's kernels follow the round's placement
+        self.task = task = task.placed(
+            1 if sharding is None else sharding.mesh.size)
         if mode not in ("client_parallel", "client_sequential"):
             raise ValueError(f"mode must be client_parallel|"
                              f"client_sequential, got {mode!r}")
@@ -432,7 +434,8 @@ class RoundEngine:
             "fed_local_steps_applied_total",
             "local SGD steps applied (sum of s over client-rounds)")
         # build-time gauges: buffers stored as flattened rows; tokens one
-        # client's local step trains (B x seq_len, 0 for non-LM tasks)
+        # client's local step trains (B x seq_len, 0 for non-LM tasks);
+        # SSD layers on the fused scan
         tel.gauge("engine_lane_dense_buffers",
                   "per-sample buffers stored as flattened rows").set(
             sum(spec.row_shape != spec.shape
@@ -440,6 +443,9 @@ class RoundEngine:
         tel.gauge("engine_tokens_per_step",
                   "tokens one client's local step trains").set(
             self.B * task.tokens_per_sample)
+        tel.gauge("engine_ssd_fused_layers",
+                  "SSD layers that run as the fused Pallas scan").set(
+            task.ssd_fused_layers)
 
     def _param_specs(self, params):
         """The task's per-leaf PartitionSpecs (None => replicated),

@@ -37,6 +37,8 @@ Usage::
 """
 from __future__ import annotations
 
+import copy
+import dataclasses
 import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
@@ -82,10 +84,18 @@ class ClientTask:
                        axes that don't exist on a given mesh; they are
                        filtered per-mesh at placement time.
     tokens_per_sample — tokens one sample trains (0 for non-LM tasks).
+    ssd_fused_layers — SSD layers that run as the fused Pallas scan (0 for
+                       tasks without SSD layers).
+    placed(n)        — the task as a round placed on n devices runs it
+                       (itself, unless the placement picks its kernels).
     """
 
     buffers: Dict[str, BufferSpec] = {}
     tokens_per_sample: int = 0
+    ssd_fused_layers: int = 0
+
+    def placed(self, devices: int) -> "ClientTask":
+        return self
 
     def loss_fn(self, params, batch):
         raise NotImplementedError
@@ -154,6 +164,28 @@ class LMTask(ClientTask):
         self.buffers = {"tokens": BufferSpec(tail, np.int32)}
 
     # -- engine protocol ------------------------------------------------------
+    def placed(self, devices: int) -> "LMTask":
+        """On one device the SSD runs as the fused Pallas scan where its
+        shapes fit (``ssm_kernel``); a round sharded over more keeps the
+        jnp path, since GSPMD cannot partition a Mosaic call."""
+        from repro.models.ssd import scan_fused
+        cfg = dataclasses.replace(self.cfg, ssm_kernel=devices == 1)
+        if scan_fused(cfg, self.seq_len) == scan_fused(self.cfg,
+                                                       self.seq_len):
+            return self                  # the placement changes no path
+        task = copy.copy(self)
+        task.cfg = cfg
+        return task
+
+    @property
+    def ssd_fused_layers(self) -> int:
+        from repro.models.params import block_kinds
+        from repro.models.ssd import scan_fused
+        if not scan_fused(self.cfg, self.seq_len):
+            return 0
+        return sum(n for _, kind, n in block_kinds(self.cfg)
+                   if kind in ("ssm", "hybrid"))
+
     def loss_fn(self, params, batch):
         from repro.models import transformer
         return transformer.train_loss(params, self.cfg, batch)

@@ -7,6 +7,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ssd_chunk
 from repro.models.common import rmsnorm, safe_concat
 
 
@@ -20,8 +21,30 @@ def segsum(x):
     return jnp.where(mask, diff, -jnp.inf)
 
 
-def ssd_chunked(x, dt, A, B, C, chunk: int, h0=None):
+def chunk_len(chunk: int, S: int) -> int:
+    """The chunk ``ssd_chunked`` takes for S positions: ``chunk``, or S
+    if shorter, halved until it divides S."""
+    Q = min(chunk, S)
+    while S % Q:
+        Q //= 2
+    return Q
+
+
+def scan_fused(cfg, S: int) -> bool:
+    """Whether ``mamba_mixer`` runs its SSD over S positions as the fused
+    Pallas op: the config asks for it (``ssm_kernel``) and
+    ``ssd_chunk.scan_fits`` takes its shapes on this backend."""
+    return bool(cfg.ssm_kernel) and ssd_chunk.scan_fits(
+        chunk_len(cfg.ssm_chunk, S), cfg.ssm_n_heads, cfg.ssm_head_dim,
+        cfg.ssm_n_groups)
+
+
+def ssd_chunked(x, dt, A, B, C, chunk: int, h0=None, kernel=False):
     """Chunked SSD scan; its ops carry the device scope ``lm.ssd``.
+
+    With ``kernel`` set, where ``ssd_chunk.scan_fits`` takes the shapes,
+    the whole scan is the fused Pallas op ``ssd_chunk.ssd_scan``; the
+    jnp path below runs everywhere else.
 
     x:  (Bb, S, H, P)     head inputs
     dt: (Bb, S, H)        post-softplus step sizes
@@ -35,12 +58,20 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, h0=None):
         Bb, S, H, Pd = x.shape
         G, N = B.shape[2], B.shape[3]
         hg = H // G
-        Q = min(chunk, S)
-        while S % Q:
-            Q //= 2
+        Q = chunk_len(chunk, S)
         nc = S // Q
 
         f32 = jnp.float32
+        if kernel and ssd_chunk.scan_fits(Q, H, Pd, G):
+            dA = dt.astype(f32) * A.astype(f32)
+            cum = jnp.cumsum(dA.reshape(Bb, nc, Q, H), axis=2)
+            if h0 is None:
+                h0 = jnp.zeros((Bb, G, hg, Pd, N), f32)
+            y, h_last = ssd_chunk.ssd_scan(
+                x.reshape(Bb, S, H * Pd), dt, cum.reshape(Bb, S, H), B, C,
+                h0, chunk=Q)
+            return y.reshape(Bb, S, H, Pd), h_last
+
         xr = x.reshape(Bb, nc, Q, G, hg, Pd).astype(f32)
         dtr = dt.reshape(Bb, nc, Q, G, hg).astype(f32)
         Br = B.reshape(Bb, nc, Q, G, N).astype(f32)
@@ -163,7 +194,6 @@ def mamba_mixer(p, u, cfg, cache=None, decode=False):
                 C_[:, 0].reshape(-1, G, N),
             )
             y = y[:, None]                                  # (Bb,1,H,P)
-            x_skip = x.reshape(*x.shape[:2], H, Pd)
             new_cache = {"conv": new_conv, "state": h}
         else:
             y_conv = _causal_conv(xBC, p["conv_w"], p["conv_b"])
@@ -175,14 +205,15 @@ def mamba_mixer(p, u, cfg, cache=None, decode=False):
                 B_.reshape(Bb, S, G, N), C_.reshape(Bb, S, G, N),
                 cfg.ssm_chunk,
                 h0=cache["state"] if cache is not None else None,
+                kernel=cfg.ssm_kernel,
             )
-            x_skip = x.reshape(Bb, S, H, Pd)
             new_cache = None
             if cache is not None:  # prefill
                 new_conv = xBC[:, -(K - 1):, :]
                 new_cache = {"conv": new_conv, "state": h}
 
-        y = y + p["D"].astype(y.dtype)[:, None] * x_skip
-        y = y.reshape(*y.shape[:2], d_in)
+        # the D skip in the (Bb, S, H*P) layout x arrives in
+        y = (y.reshape(*y.shape[:2], d_in)
+             + jnp.repeat(p["D"].astype(y.dtype), Pd) * x)
         y = rmsnorm(y * jax.nn.silu(z), p["ssm_norm"], cfg.norm_eps)
         return y @ p["out_proj"], new_cache

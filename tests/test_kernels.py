@@ -251,3 +251,112 @@ def test_ssd_intra_chunk_matches_model_ssd():
     y_k = jnp.moveaxis(y_k.reshape(Bb, H, S, P), 1, 2)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_model),
                                rtol=2e-3, atol=2e-3)
+
+
+# --- ssd_scan: the whole chunked SSD as one op with its backward pass -----
+# Q=128 tiles on the chip; hg > 1 heads share each group's B and C.
+
+SCAN_Q = 128
+
+
+def _scan_inputs(Bb, S, H, P, G, N, seed=0):
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    return (f(Bb, S, H, P),
+            jnp.asarray(rng.uniform(0.01, 0.2, (Bb, S, H)), jnp.float32),
+            jnp.asarray(-rng.uniform(0.5, 2.0, (H,)), jnp.float32),
+            f(Bb, S, G, N), f(Bb, S, G, N), f(Bb, G, H // G, P, N))
+
+
+def _scan_of_ssd_chunked(x, dt, A, B, C, h0):
+    from repro.models.ssd import ssd_chunked
+    return ssd_chunked(x, dt, A, B, C, chunk=SCAN_Q, h0=h0)
+
+
+def _scan_of_recurrence(x, dt, A, B, C, h0):
+    """The per-position recurrence (models/ssd.ssd_decode_step), scanned."""
+    from repro.models.ssd import ssd_decode_step
+
+    def step(h, t):
+        x_t, dt_t, B_t, C_t = t
+        y, h = ssd_decode_step(h, x_t, dt_t, A, B_t, C_t)
+        return h, y
+
+    xs = [jnp.moveaxis(a, 1, 0) for a in (x, dt, B, C)]
+    h, ys = jax.lax.scan(step, h0, xs)
+    return jnp.moveaxis(ys, 0, 1), h
+
+
+def _scan_fused(x, dt, A, B, C, h0):
+    from repro.kernels.ssd_chunk import ssd_scan
+    Bb, S, H, P = x.shape
+    cum = jnp.cumsum((dt * A).reshape(Bb, S // SCAN_Q, SCAN_Q, H), axis=2)
+    y, h = ssd_scan(x.reshape(Bb, S, H * P), dt, cum.reshape(Bb, S, H), B,
+                    C, h0, chunk=SCAN_Q, interpret=True)
+    return y.reshape(Bb, S, H, P), h
+
+
+def _scan_loss(fn, w, wh):
+    def loss(*a):
+        y, h = fn(*a)
+        return jnp.sum(y * w) + jnp.sum(h * wh)
+    return loss
+
+
+def _assert_close(got, want, rtol):
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=rtol, atol=rtol * scale)
+
+
+@pytest.mark.parametrize("H,P,G,N", [
+    (4, 64, 1, 128), (4, 64, 2, 16), (8, 32, 2, 16), (2, 128, 1, 16),
+    (20, 64, 1, 16)])
+def test_ssd_scan_matches_ssd_chunked_and_recurrence(H, P, G, N):
+    """y, h_last and d/d(x, dt, A, B, C, h0) of the fused scan equal the
+    jnp chunked SSD and the per-position recurrence: two chunks of Q=128,
+    heads of P=32/64/128 filling 128-lane tiles, groups of 2-20 heads
+    (20: a group over several head steps)."""
+    args = _scan_inputs(1, 2 * SCAN_Q, H, P, G, N, seed=H + P + G + N)
+    rng = np.random.default_rng(1)
+    w = jnp.asarray(rng.normal(size=args[0].shape), jnp.float32)
+    wh = jnp.asarray(rng.normal(size=args[5].shape), jnp.float32)
+    got = _scan_fused(*args)
+    grads = jax.grad(_scan_loss(_scan_fused, w, wh), argnums=range(6))(
+        *args)
+    for oracle in (_scan_of_ssd_chunked, _scan_of_recurrence):
+        for g, h in zip(got, oracle(*args)):
+            _assert_close(g, h, 2e-5)
+        want = jax.grad(_scan_loss(oracle, w, wh), argnums=range(6))(*args)
+        for g, h in zip(grads, want):
+            _assert_close(g, h, 1e-4)
+
+
+@pytest.mark.parametrize("transform", ["vmap", "checkpoint"])
+def test_ssd_scan_under_vmap_and_checkpoint(transform):
+    """The client axis (vmap) and the layer remat (checkpoint) leave the
+    op's values and gradients those of the jnp chunked SSD."""
+    clients = [_scan_inputs(1, 2 * SCAN_Q, 4, 64, 2, 16, seed=s)
+               for s in (3, 4)]
+    args = [jnp.stack(a) for a in zip(*clients)]
+    rng = np.random.default_rng(2)
+    w = jnp.asarray(rng.normal(size=args[0].shape), jnp.float32)
+    if transform == "vmap":
+        wrap = jax.vmap
+    else:
+        wrap = lambda f: jax.vmap(jax.checkpoint(f))
+
+    def loss(fn):
+        # squared, so the backward pass needs the (rematted) forward
+        def f(*a):
+            y, h = wrap(fn)(*a)
+            return jnp.sum(jnp.square(y) * w) + jnp.sum(jnp.square(h))
+        return f
+
+    for g, h in zip(wrap(_scan_fused)(*args),
+                    wrap(_scan_of_ssd_chunked)(*args)):
+        _assert_close(g, h, 2e-5)
+    got = jax.grad(loss(_scan_fused), argnums=range(6))(*args)
+    want = jax.grad(loss(_scan_of_ssd_chunked), argnums=range(6))(*args)
+    for g, h in zip(got, want):
+        _assert_close(g, h, 1e-4)

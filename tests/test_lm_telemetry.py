@@ -82,6 +82,30 @@ def test_applied_steps_counter_and_tokens_gauge():
     assert mlp.registry.gauge("engine_tokens_per_step").value == 0
 
 
+def test_ssd_fused_layers_gauge(monkeypatch):
+    """engine_ssd_fused_layers counts the SSD layers on the fused scan, by
+    the predicate ssd_chunked uses on the engine's placed task: none on
+    the CPU and none without SSD layers; on a TPU every layer of a round
+    placed on one device where the chunk tiles, none of a sharded one."""
+    from repro.kernels import ssd_chunk
+
+    tel = Telemetry()
+    sch = lm_scheduler(telemetry=tel)
+    assert tel.registry.gauge("engine_ssd_fused_layers").value == 0
+    assert not sch.engine.task.cfg.ssm_kernel   # no path changed: unplaced
+    mlp = Telemetry()
+    make_scheduler(make_clients(3, seed=0), seed=0, telemetry=mlp)
+    assert mlp.registry.gauge("engine_ssd_fused_layers").value == 0
+
+    monkeypatch.setattr(ssd_chunk, "resolve_interpret", lambda i: False)
+    full = LMTask(get_config("mamba2-130m"), seq_len=2048)
+    assert full.ssd_fused_layers == 0                  # not placed yet
+    assert full.placed(1).ssd_fused_layers == 24
+    assert full.placed(1).cfg.ssm_kernel and not full.cfg.ssm_kernel
+    assert full.placed(4) is full                      # sharded: jnp
+    assert _task().placed(1).ssd_fused_layers == 0     # Q = 16 < 128
+
+
 def test_lm_round_is_bit_identical_with_telemetry():
     runs = []
     for tel in (None, Telemetry()):

@@ -76,3 +76,94 @@ def test_segsum_lower_triangular():
     assert np.all(np.isneginf(M[np.triu_indices(5, 1)]))
     np.testing.assert_allclose(np.diag(M), 0.0, atol=1e-6)
     np.testing.assert_allclose(M[3, 1], float(x[2] + x[3]), rtol=1e-5)
+
+
+def _fits_everywhere(monkeypatch):
+    """ssd_chunked's fused path taken off the chip: scan_fits says yes and
+    the kernels run in Pallas interpret mode."""
+    from repro.kernels import ssd_chunk
+    monkeypatch.setattr(ssd_chunk, "scan_fits", lambda *a: True)
+
+
+@pytest.mark.parametrize("with_h0", [False, True])
+def test_fused_scan_path_matches_jnp_path(monkeypatch, with_h0):
+    """ssd_chunked on the fused scan (kernel=True) gives the jnp path's y,
+    h_last and gradients at a shape that tiles on the chip (Q=128, two
+    chunks, 2 groups of 2 heads of P=64)."""
+    rng = np.random.default_rng(5)
+    Bb, S, H, P, G, N = 2, 256, 4, 64, 2, 16
+    x = jnp.asarray(rng.normal(size=(Bb, S, H, P)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.01, 0.2, (Bb, S, H)), jnp.float32)
+    A = jnp.asarray(-rng.uniform(0.5, 2.0, (H,)), jnp.float32)
+    B = jnp.asarray(rng.normal(size=(Bb, S, G, N)), jnp.float32)
+    C = jnp.asarray(rng.normal(size=(Bb, S, G, N)), jnp.float32)
+    h0 = (jnp.asarray(rng.normal(size=(Bb, G, H // G, P, N)), jnp.float32)
+          if with_h0 else None)
+    w = jnp.asarray(rng.normal(size=(Bb, S, H, P)), jnp.float32)
+    _fits_everywhere(monkeypatch)
+
+    def run(kernel):
+        def f(*a):
+            y, h = ssd_chunked(*a, chunk=128, h0=h0, kernel=kernel)
+            return y, h, jnp.sum(y * w) + jnp.sum(jnp.square(h))
+        out = f(x, dt, A, B, C)
+        grads = jax.grad(lambda *a: f(*a)[2], argnums=range(5))(
+            x, dt, A, B, C)
+        return out, grads
+
+    for g, h in zip(jax.tree.leaves(run(True)), jax.tree.leaves(run(False))):
+        scale = float(jnp.max(jnp.abs(h)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(h),
+                                   rtol=1e-4, atol=1e-4 * scale)
+
+
+def test_fused_scan_predicate(monkeypatch):
+    """The fused scan is taken only where the config asks for it and
+    Mosaic compiles (a TPU backend), with a chunk of a multiple of 128 and
+    each group's heads filling 128-lane tiles; mamba2-130m at S=2048
+    (Q=256, 24 heads of P=64, N=128) qualifies."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.kernels import ssd_chunk
+    from repro.models.ssd import scan_fused
+    cfg = dataclasses.replace(get_config("mamba2-130m"), ssm_kernel=True)
+    assert not ssd_chunk.scan_fits(256, 24, 64, 1)         # CPU backend
+    assert not scan_fused(cfg, 2048)
+    monkeypatch.setattr(ssd_chunk, "resolve_interpret",
+                        lambda interpret: False)           # "on a TPU"
+    assert ssd_chunk.scan_fits(256, 24, 64, 1)
+    assert scan_fused(cfg, 2048)                           # Q = 256
+    assert ssd_chunk.scan_fits(128, 8, 32, 2)              # 4 heads a tile
+    assert not ssd_chunk.scan_fits(64, 24, 64, 1)         # Q < 128
+    assert not ssd_chunk.scan_fits(256, 24, 48, 1)        # P ∤ 128
+    assert not ssd_chunk.scan_fits(256, 3, 64, 1)         # half a tile
+    assert not scan_fused(cfg, 96)                         # Q = 96
+    assert not scan_fused(cfg.reduced(), 2048)             # chunk 32
+    assert not scan_fused(dataclasses.replace(cfg, ssm_kernel=False), 2048)
+
+
+def test_model_loss_on_fused_scan_matches_jnp(monkeypatch):
+    """The whole reduced mamba2 (2 layers, 16 heads of P=32) with its SSD
+    on the fused scan: the train loss and every parameter's gradient are
+    the jnp path's (the mixer's D skip and layouts included)."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import transformer
+    from repro.models.params import init_params
+    cfg = dataclasses.replace(get_config("mamba2-130m").reduced(),
+                              ssm_chunk=128, dtype="float32")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    t = jax.random.randint(jax.random.PRNGKey(1), (1, 257), 0, cfg.vocab)
+    batch = {"tokens": t[:, :-1], "labels": t[:, 1:]}
+    _fits_everywhere(monkeypatch)
+
+    def run(kernel):
+        c = dataclasses.replace(cfg, ssm_kernel=kernel)
+        return jax.value_and_grad(transformer.train_loss)(params, c, batch)
+
+    for g, h in zip(jax.tree.leaves(run(True)), jax.tree.leaves(run(False))):
+        scale = float(jnp.max(jnp.abs(h)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(h),
+                                   rtol=1e-4, atol=1e-4 * scale)

@@ -104,3 +104,30 @@ def test_ssd_intra_chunk_compiles(one_chip):
         cum, C, B, x, interpret=False),
         sds(G, Q), sds(G, Q, N), sds(G, Q, N), sds(G, Q, P))
 
+
+def test_ssd_scan_fwd_and_bwd_compile(one_chip):
+    """The fused SSD scan, forward and backward under the layer remat, at
+    mamba2-fed's shapes: 8 sequences (4 clients x B=2) of 8 chunks of
+    Q=256, 24 heads of P=64 in one group, N=128; x, B and C in bf16 as
+    the mixer hands them over."""
+    Bb, S, H, G, P, N = 8, 2048, 24, 1, 64, 128
+    sds = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def op(x, dt, cum, B, C, h0):
+        return ssd_chunk.ssd_scan(x, dt, cum, B, C, h0, chunk=CHUNK,
+                                  interpret=False)
+
+    def loss(*a):
+        y, h = jax.checkpoint(op)(*a)
+        return jnp.sum(jnp.square(y.astype(f32))) + jnp.sum(h)
+
+    args = (sds(bf16, Bb, S, H * P), sds(f32, Bb, S, H), sds(f32, Bb, S, H),
+            sds(bf16, Bb, S, G, N), sds(bf16, Bb, S, G, N),
+            sds(f32, Bb, G, H // G, P, N))
+    _compile(op, *args)
+    text = jax.jit(jax.grad(loss, argnums=range(6))).lower(
+        *args).compile().as_text()
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+
