@@ -98,16 +98,24 @@ def _host_overhead_fraction(tr, span):
     return max(0.0, 1.0 - dev[0] / total)
 
 
+def _time(fn, *args, reps=5):
+    """Mean wall microseconds of fn(*args) after one warm-up call."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        jax.block_until_ready(fn(*args))
+    return (time.perf_counter() - t0) / reps * 1e6
+
+
 def _agg_us(n_clients):
     """Single-launch weighted_agg time at the benchmark model size."""
-    from benchmarks.kernels_bench import _time
-    from repro.kernels import ops
+    from repro.kernels.weighted_agg import weighted_agg
     params = init_small(jax.random.PRNGKey(0), CFG)
     D = sum(p.size for p in jax.tree.leaves(params))
     key = jax.random.PRNGKey(0)
     c = jax.random.uniform(key, (n_clients,))
     d = jax.random.normal(key, (n_clients, D), jnp.float32)
-    return _time(lambda: ops.weighted_agg(c, d, block=1024)), D
+    return _time(lambda: weighted_agg(c, d, block=1024)), D
 
 
 def run(span=32, reps=7, n_clients=12, chunk=32):

@@ -1,5 +1,5 @@
-"""Benchmark harness: one function per paper table + kernel micro-bench +
-roofline summary.  Prints ``name,us_per_call,derived`` style CSV blocks.
+"""Benchmark harness: one function per paper table + roofline summary.
+Prints ``name,us_per_call,derived`` style CSV blocks.
 
   PYTHONPATH=src python -m benchmarks.run            # quick set
   PYTHONPATH=src python -m benchmarks.run --full     # full paper tables
@@ -80,12 +80,6 @@ def main() -> None:
             if k != "events":
                 print(f"{k},{v}")
         return
-
-    print("# kernels: name,us_per_call,config")
-    from benchmarks.kernels_bench import run_all as kern_all
-    for name, us, cfg in kern_all():
-        print(f"{name},{us:.1f},{cfg}")
-    sys.stdout.flush()
 
     if not args.skip_engine:
         from benchmarks.engine_bench import main as engine_main

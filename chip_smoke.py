@@ -137,20 +137,18 @@ def kernel_parity(wire: str, K: int, D: int) -> float:
     import jax
 
     from repro.core.compression import quantize_chunked
-    from repro.kernels import ops, ref
+    from repro.kernels import ref
+    from repro.kernels.weighted_agg import weighted_agg
 
     kc, kd = jax.random.split(jax.random.PRNGKey(7))
     coeffs = jax.random.uniform(kc, (K,))
     deltas = 0.01 * jax.random.normal(kd, (K, D))
+    args, kw = (deltas,), {}
+    if wire == "int8":
+        args, kw = quantize_chunked(deltas, chunk=256), {"chunk": 256}
     with jax.default_matmul_precision("highest"):
-        if wire == "int8":
-            payload, scales = quantize_chunked(deltas, chunk=256)
-            got = ops.weighted_agg_quant(coeffs, payload, scales, chunk=256)
-            want = ref.weighted_agg_quant_ref(coeffs, payload, scales,
-                                              chunk=256)
-        else:
-            got = ops.weighted_agg(coeffs, deltas)
-            want = ref.weighted_agg_ref(coeffs, deltas)
+        got = weighted_agg(coeffs, *args, **kw)
+        want = ref.weighted_agg_ref(coeffs, *args, **kw)
     return max_diff(got, want)
 
 
@@ -184,7 +182,7 @@ def paper_phase(n_clients: int = PAPER_CLIENTS,
             runs[name] = tr
             if name == "system":
                 eng = tr.engine
-                interp = resolve_interpret(eng.interpret)
+                interp = resolve_interpret(None)
                 d_total = sum(x.size for x in jax.tree.leaves(tr.params))
                 log(f"paper[{wire}] agg={eng.agg} interpret={interp} "
                     f"slots={eng.capacity} params={d_total:,}")
@@ -249,24 +247,19 @@ def sharded_kernels(fs, K: int, D: int) -> None:
     import jax
 
     from repro.core.compression import quantize_chunked
-    from repro.kernels import ops
+    from repro.kernels.weighted_agg import weighted_agg, weighted_agg_sharded
 
     kc, kd = jax.random.split(jax.random.PRNGKey(7))
     coeffs = jax.random.uniform(kc, (K,))
     deltas = 0.01 * jax.random.normal(kd, (K, D))
-    payload, scales = quantize_chunked(deltas, chunk=256)
     for wire in WIRES:
+        args, kw = (deltas,), {}
+        if wire == "int8":
+            args, kw = quantize_chunked(deltas, chunk=256), {"chunk": 256}
+        want = weighted_agg(coeffs, *args, **kw)
         for kb in (None, 8):
-            if wire == "int8":
-                want = ops.weighted_agg_quant(coeffs, payload, scales,
-                                              chunk=256)
-                got = ops.weighted_agg_quant_sharded(
-                    coeffs, payload, scales, chunk=256, mesh=fs.mesh,
-                    k_block=kb)
-            else:
-                want = ops.weighted_agg(coeffs, deltas)
-                got = ops.weighted_agg_sharded(coeffs, deltas, mesh=fs.mesh,
-                                               k_block=kb)
+            got = weighted_agg_sharded(coeffs, *args, mesh=fs.mesh,
+                                       k_block=kb, **kw)
             err = max_diff(got, want)
             log(f"sharded[{wire}] kernel psum K={K} D={D} k_block={kb} "
                 f"max_abs_err={err:.3e} (bound {KERNEL_BOUND:g})")

@@ -94,8 +94,8 @@ def _apply_flat(params, agg):
     return jax.tree.unflatten(treedef, outs)
 
 
-def aggregate_deltas_flat(params, deltas, coeffs, *, block: int = 2048,
-                          interpret=None, sharding=None, compression=None):
+def aggregate_deltas_flat(params, deltas, coeffs, *, sharding=None,
+                          compression=None):
     """Same contract as aggregate_deltas, but the whole model is flattened
     into a single (C, D_total) buffer and reduced with ONE weighted_agg
     Pallas launch (instead of one scaled-add tree per leaf).
@@ -106,50 +106,35 @@ def aggregate_deltas_flat(params, deltas, coeffs, *, block: int = 2048,
     path of the sharded round engine).
 
     compression: optional CompressionSpec/str (core.compression).  The
-    int8 kinds quantize the flat buffer on-device and reduce it with the
-    fused dequant-and-reduce kernel — under sharding the payload+scales
-    are what shard over the federation axes, so only compressed bytes
-    (plus one f32 psum) cross the client dim.  bf16 is a plain cast into
-    the existing kernel (it reduces any float dtype in f32)."""
+    int8 kinds quantize the flat buffer on-device and the kernel fuses
+    dequantization into the reduction — under sharding the codes and
+    scales are what shard over the federation axes, so only compressed
+    bytes (plus one f32 psum) cross the client dim.  bf16 is a plain cast
+    (the kernel reduces any float dtype in f32)."""
     from repro.core.compression import compress_flat, resolve_compression
-    from repro.kernels import ops  # kernels never import core: no cycle
+    # kernels never import core: no cycle
+    from repro.kernels.weighted_agg import (weighted_agg,
+                                            weighted_agg_sharded)
 
     spec = resolve_compression(compression)
     flat = flatten_client_deltas(deltas)
-    # shrink the tile for models smaller than one default block (pad waste)
     D = flat.shape[1]
-    block = min(block, max(128, -(-D // 128) * 128))
+    operands, kw = (flat,), {}
     if spec.quantized:
         with jax.named_scope("fed.flatten"):
-            payload, scales = compress_flat(flat, spec)
-        with jax.named_scope("fed.aggregate"):
-            if sharding is not None:
-                payload, scales = sharding.constrain_compressed(payload,
-                                                                scales)
-                agg = ops.weighted_agg_quant_sharded(
-                    coeffs.astype(jnp.float32), payload, scales,
-                    chunk=spec.chunk, mesh=sharding.mesh,
-                    axis=sharding.axis, block=block, interpret=interpret)
-            else:
-                agg = ops.weighted_agg_quant(
-                    coeffs.astype(jnp.float32), payload, scales,
-                    chunk=spec.chunk, block=block, interpret=interpret)
-            agg = agg[:D]
-    else:
-        if spec.kind == "bf16":
-            with jax.named_scope("fed.flatten"):
-                flat = flat.astype(jnp.bfloat16)
-        with jax.named_scope("fed.aggregate"):
-            if sharding is not None:
-                flat = sharding.constrain_client(flat)
-                agg = ops.weighted_agg_sharded(
-                    coeffs.astype(jnp.float32), flat, mesh=sharding.mesh,
-                    axis=sharding.axis, block=block, interpret=interpret)
-            else:
-                agg = ops.weighted_agg(coeffs.astype(jnp.float32), flat,
-                                       block=block, interpret=interpret)
+            operands, kw = compress_flat(flat, spec), {"chunk": spec.chunk}
+    elif spec.kind == "bf16":
+        with jax.named_scope("fed.flatten"):
+            operands = (flat.astype(jnp.bfloat16),)
     with jax.named_scope("fed.aggregate"):
-        return _apply_flat(params, agg)
+        if sharding is None:
+            agg = weighted_agg(coeffs, *operands, **kw)
+        else:
+            # each shard owns its own clients' (compressed) bytes
+            operands = [sharding.constrain_client(x) for x in operands]
+            agg = weighted_agg_sharded(coeffs, *operands, mesh=sharding.mesh,
+                                       axis=sharding.axis, **kw)
+        return _apply_flat(params, agg[:D])
 
 
 def aggregate_deltas_compressed_ref(params, deltas, coeffs, compression):

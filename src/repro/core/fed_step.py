@@ -108,9 +108,8 @@ def _log_batch_padding(b: int, n_shards: int, pad: int) -> None:
 
 
 def fed_round_parallel(loss_fn, params, batches, alpha, coeffs, eta, *,
-                       agg: str = "tree", interpret=None,
-                       with_metrics: bool = True, sharding=None,
-                       param_specs=None, compression=None):
+                       agg: str = "tree", with_metrics: bool = True,
+                       sharding=None, param_specs=None, compression=None):
     """batches: pytree (C, E, ...); alpha: (C, E); coeffs: (C,).
     Returns (new_params, metrics).
 
@@ -142,7 +141,6 @@ def fed_round_parallel(loss_fn, params, batches, alpha, coeffs, eta, *,
         deltas = _constrain_client_deltas(sharding, deltas, param_specs)
     if agg == "flat":
         new_params = aggregate_deltas_flat(params, deltas, coeffs,
-                                           interpret=interpret,
                                            sharding=sharding,
                                            compression=spec)
     elif spec.active:
@@ -219,11 +217,10 @@ def fed_round_sequential(loss_fn, params, batches, alpha, coeffs, eta, *,
 
 
 def make_fed_round(loss_fn, mode: str = "client_parallel",
-                   agg: str = "tree", interpret=None, compression=None):
+                   agg: str = "tree", compression=None):
     """Returns fed_round(params, batches, alpha, coeffs, eta)."""
     if mode == "client_parallel":
         return functools.partial(fed_round_parallel, loss_fn, agg=agg,
-                                 interpret=interpret,
                                  compression=compression)
     if mode != "client_sequential":
         raise ValueError(f"mode must be client_parallel|client_sequential, "

@@ -84,7 +84,7 @@ class FederatedTrainer:
                  bound_terms: Optional[BoundTerms] = None,
                  seed: int = 0, engine: Optional[str] = "plan",
                  chunk_size: int = 16, agg: str = "auto",
-                 interpret=None, donate: Optional[bool] = None,
+                 donate: Optional[bool] = None,
                  compression=None, with_metrics: bool = False,
                  sharding=None, task=None,
                  mode: str = "client_parallel"):
@@ -121,7 +121,6 @@ class FederatedTrainer:
                              f"got {engine!r}")
         self.chunk_size = chunk_size
         self.agg = agg
-        self.interpret = interpret
         self.donate = donate
         self.with_metrics = with_metrics
         self.sharding = sharding
@@ -144,7 +143,7 @@ class FederatedTrainer:
                 task=self.task, clients=self.clients,
                 local_epochs=self.E, batch_size=self.B, scheme=self.scheme,
                 eta0=self.eta0, chunk_size=self.chunk_size, agg=self.agg,
-                interpret=self.interpret, donate=self.donate,
+                donate=self.donate,
                 compression=self.compression,
                 with_metrics=self.with_metrics, sharding=self.sharding,
                 mode=self.mode)

@@ -311,7 +311,7 @@ class RoundEngine:
                  batch_size: int, loss_fn=None, task=None,
                  scheme: str = "C", eta0: float = 0.01,
                  chunk_size: int = 16, agg: str = "auto",
-                 interpret=None, donate: Optional[bool] = None,
+                 donate: Optional[bool] = None,
                  with_metrics: bool = False,
                  capacity: Optional[int] = None,
                  max_samples: Optional[int] = None,
@@ -353,7 +353,6 @@ class RoundEngine:
             agg = ("flat" if (jax.default_backend() == "tpu"
                               or self.compression.quantized) else "tree")
         self.agg = agg
-        self.interpret = interpret
         self.with_metrics = with_metrics
         if donate is None:  # CPU jit cannot reuse donated buffers
             donate = jax.default_backend() != "cpu"
@@ -679,9 +678,9 @@ class RoundEngine:
             agg = "tree" if pspecs is not None else self.agg
             new_params, m = fed_round_parallel(
                 self._step_loss, params, rows, alpha, coeffs, eta,
-                agg=agg, interpret=self.interpret,
-                with_metrics=self.with_metrics, sharding=self.sharding,
-                param_specs=pspecs, compression=self.compression)
+                agg=agg, with_metrics=self.with_metrics,
+                sharding=self.sharding, param_specs=pspecs,
+                compression=self.compression)
         return new_params, {"s": s, "eta": eta,
                             "delta_norm": m["delta_norm"]}
 

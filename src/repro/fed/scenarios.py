@@ -246,7 +246,7 @@ def make_scenario(name: str, *, seed: int = 0, **kwargs) -> Scenario:
 
 def build_scheduler(sc: Scenario, *, mode: str = "device",
                     chunk_size: int = 16, agg: str = "auto",
-                    interpret=None, compression=None,
+                    compression=None,
                     with_metrics: bool = False, telemetry=None,
                     engine_mode: str = "client_parallel",
                     capacity: Optional[int] = None,
@@ -270,7 +270,7 @@ def build_scheduler(sc: Scenario, *, mode: str = "device",
         max_samples=sc.max_samples,
         local_epochs=sc.local_epochs, batch_size=sc.batch_size,
         scheme=sc.scheme, eta0=sc.eta0, chunk_size=chunk_size, agg=agg,
-        interpret=interpret, compression=compression,
+        compression=compression,
         with_metrics=with_metrics, seed=sc.seed,
         mode=mode, events=sc.events, telemetry=telemetry,
         engine_mode=engine_mode, bank=bank, prefetch=prefetch)

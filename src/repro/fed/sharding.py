@@ -151,15 +151,6 @@ class FedSharding:
         return jax.tree.map(
             lambda l: self.constrain_client(l, axis_dim), tree)
 
-    def constrain_compressed(self, payload, scales):
-        """Constrain a compressed client-delta pair (int8 payload
-        (C, Dp) + per-chunk f32 scales (C, Dp/chunk)) so each shard owns
-        its own clients' compressed bytes — the quantized local
-        dequant-and-reduce launch then runs shard-local and only the f32
-        (D,) partial crosses devices in the psum epilogue."""
-        return (self.constrain_client(payload),
-                self.constrain_client(scales))
-
     def constrain_replicated(self, tree):
         repl = self.replicated()
         return jax.tree.map(

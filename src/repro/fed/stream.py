@@ -97,7 +97,7 @@ class StreamScheduler:
                  local_epochs: int = 5, batch_size: int = 10,
                  scheme: str = "C", eta0: float = 0.01,
                  chunk_size: int = 16, agg: str = "auto",
-                 interpret=None, donate: Optional[bool] = None,
+                 donate: Optional[bool] = None,
                  compression=None, with_metrics: bool = False,
                  reboot_boost: float = 3.0, fast_reboot: bool = True,
                  horizon: Optional[int] = None,
@@ -138,8 +138,7 @@ class StreamScheduler:
                 loss_fn=loss_fn, task=task, clients=clients,
                 local_epochs=local_epochs, batch_size=batch_size,
                 scheme=scheme, eta0=eta0, chunk_size=chunk_size, agg=agg,
-                interpret=interpret, donate=donate,
-                compression=compression,
+                donate=donate, compression=compression,
                 with_metrics=with_metrics, capacity=capacity,
                 max_samples=max_samples, sharding=sharding,
                 mode=engine_mode, telemetry=telemetry)
@@ -582,7 +581,7 @@ class StreamScheduler:
     def restore(cls, path: str, *, loss_fn: Optional[Callable] = None,
                 task=None, eval_fn: Optional[Callable] = None,
                 evaluate: Optional[Callable] = None, sharding=None,
-                interpret=None, donate: Optional[bool] = None,
+                donate: Optional[bool] = None,
                 engine: Optional[RoundEngine] = None, injector=None,
                 log_spans: bool = False, telemetry=None,
                 **overrides) -> "StreamScheduler":
@@ -621,7 +620,7 @@ class StreamScheduler:
                 # pre-compression checkpoints carry no key: f32 wire
                 compression=cfg.get("compression", "none"),
                 capacity=cfg["capacity"], max_samples=cfg["max_samples"],
-                sharding=sharding, interpret=interpret, donate=donate,
+                sharding=sharding, donate=donate,
                 mode=cfg["engine_mode"], telemetry=telemetry)
         else:
             if engine.capacity != cfg["capacity"]:

@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels: <name>.py holds a kernel and its public entry, ref.py
+# the pure-jnp oracle each is tested against.  Each entry decides its own
+# tiles and interpretation (compiled on TPU, interpreted elsewhere).

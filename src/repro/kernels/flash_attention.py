@@ -65,9 +65,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                    static_argnames=("causal", "blk_q", "blk_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, blk_q: int = 128,
                     blk_k: int = 128, interpret: bool | None = None):
-    """q,k,v: (B,H,S,hd) (same H; GQA callers repeat kv heads upstream)."""
+    """q: (B,H,S,hd); k,v: (B,KV,S,hd) — kv heads repeated to H if GQA."""
     interpret = resolve_interpret(interpret)
     B, H, S, hd = q.shape
+    if k.shape[1] != H:
+        rep = H // k.shape[1]
+        k = jnp.repeat(k, rep, axis=1)
+        v = jnp.repeat(v, rep, axis=1)
     blk_q = min(blk_q, S)
     blk_k = min(blk_k, S)
     pad = (-S) % max(blk_q, blk_k)

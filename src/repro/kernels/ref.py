@@ -5,25 +5,15 @@ import jax
 import jax.numpy as jnp
 
 
-def weighted_agg_ref(coeffs, deltas):
-    """(K,), (K,D) -> (D,) f32."""
-    return jnp.einsum("k,kd->d", coeffs.astype(jnp.float32),
-                      deltas.astype(jnp.float32))
-
-
-def weighted_agg_quant_ref(coeffs, payload, scales, *, chunk):
-    """(K,), (K,Dp) int8, (K,Dp/chunk) f32 -> (Dp,) f32: dequantize then
-    reduce — the allclose target for the fused dequant-and-reduce kernel."""
-    K, Dp = payload.shape
-    deltas = (payload.astype(jnp.float32).reshape(K, Dp // chunk, chunk)
-              * scales[..., None]).reshape(K, Dp)
-    return weighted_agg_ref(coeffs, deltas)
-
-
-def masked_sgd_ref(w, g, eta_alpha):
-    return (w.astype(jnp.float32)
-            - eta_alpha.astype(jnp.float32) * g.astype(jnp.float32)
-            ).astype(w.dtype)
+def weighted_agg_ref(coeffs, deltas, scales=None, *, chunk=None):
+    """(K,), (K,D) -> (D,) f32.  With scales (K, D/chunk) f32, deltas are
+    int8 codes: dequantize, then reduce."""
+    K, D = deltas.shape
+    deltas = deltas.astype(jnp.float32)
+    if scales is not None:
+        deltas = (deltas.reshape(K, D // chunk, chunk)
+                  * scales[..., None]).reshape(K, D)
+    return jnp.einsum("k,kd->d", coeffs.astype(jnp.float32), deltas)
 
 
 def ssd_intra_chunk_ref(cum, C, B, xdt):

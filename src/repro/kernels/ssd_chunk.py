@@ -30,9 +30,6 @@ Precision: every elementwise step (segsum, exp, mask, products), the
 state and every accumulation is f32.  The matmul operands are rounded as
 XLA's default precision rounds the jnp path's f32 einsums: one bf16 pass
 on the TPU, none in interpret mode on the CPU.
-
-``ssd_intra_chunk`` is the intra-chunk term alone, forward only, one
-(batch x chunk x head) cell per grid step.
 """
 from __future__ import annotations
 
@@ -387,48 +384,3 @@ def ssd_scan(x, dt, cum, B, C, h0, *, chunk: int,
                   resolve_interpret(interpret))
     h_last = jnp.moveaxis(hl.reshape(Bb, N, H, P), 1, 3)
     return y, h_last.reshape(Bb, G, H // G, P, N)
-
-
-def _ssd_chunk_kernel(cum_ref, c_ref, b_ref, x_ref, o_ref):
-    cum = cum_ref[0]                                   # (1, Q)
-    C = c_ref[0]                                       # (Q, N)
-    B = b_ref[0]                                       # (Q, N)
-    xdt = x_ref[0]                                     # (Q, P)
-    Q = cum.shape[1]
-    s = jnp.dot(C, B.T, preferred_element_type=jnp.float32)   # (Q, Q)
-    cum_j = jnp.broadcast_to(cum, (Q, Q))              # [i, j] = cum[j]
-    diff = cum_j.T - cum_j                             # cum[i] - cum[j]
-    iota_i = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    iota_j = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(iota_j <= iota_i, jnp.exp(diff), 0.0)
-    s = s * L
-    o_ref[0] = jnp.dot(s.astype(xdt.dtype), xdt,
-                       preferred_element_type=jnp.float32
-                       ).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_intra_chunk(cum, C, B, xdt, *, interpret: bool | None = None):
-    """cum: (G_cells, Q); C, B: (G_cells, Q, N); xdt: (G_cells, Q, P).
-
-    G_cells = batch * n_chunks * n_heads (caller flattens; group-shared
-    B/C are expanded to per-head).  Returns (G_cells, Q, P) float32.
-    ``cum`` enters the kernel as (G_cells, 1, Q), so each cell's block is
-    one full-width row that satisfies the TPU's (8, 128) tiling.
-    """
-    interpret = resolve_interpret(interpret)
-    G_cells, Q, N = C.shape
-    P = xdt.shape[-1]
-    return pl.pallas_call(
-        _ssd_chunk_kernel,
-        grid=(G_cells,),
-        in_specs=[
-            pl.BlockSpec((1, 1, Q), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, Q, N), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, Q, N), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, Q, P), lambda g: (g, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Q, P), lambda g: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((G_cells, Q, P), jnp.float32),
-        interpret=interpret,
-    )(cum.reshape(G_cells, 1, Q), C, B, xdt)

@@ -161,11 +161,11 @@ def gqa_attention(p, x, cfg, positions, cache=None, decode=False):
             ka, va = _expand_kv(k, v, cfg)
         if cfg.attn_impl == "flash" and not cfg.sliding_window:
             # Pallas flash kernel (forward-only: serving prefill path)
-            from repro.kernels import ops as kops
+            from repro.kernels.flash_attention import flash_attention
             if ka.shape[2] < q.shape[2]:
                 ka, va = _expand_kv(k, v, cfg)
-            o = kops.flash_attention(q.swapaxes(1, 2), ka.swapaxes(1, 2),
-                                     va.swapaxes(1, 2)).swapaxes(1, 2)
+            o = flash_attention(q.swapaxes(1, 2), ka.swapaxes(1, 2),
+                                va.swapaxes(1, 2)).swapaxes(1, 2)
         else:
             o = causal_attention(q, ka, va, window=cfg.sliding_window,
                                  q_offset=positions[0],

@@ -6,7 +6,7 @@ interpreter (same pattern as tests/_sharded_check.py).  On success the
 last stdout line is ``RESULT {json}``.
 
 Checks (the sharded acceptance criteria of the compressed-delta path):
-  1. weighted_agg_quant_sharded == the single-device quantized kernel
+  1. weighted_agg_sharded with scales == the single-device int8 kernel
      (identical codes/scales, shard-local dequant matvec + f32 psum
      epilogue vs one full reduction), single- and multi-block-K;
   2. a sharded int8 StreamScheduler matches the single-device int8 one
@@ -58,16 +58,15 @@ def make_sched(sharding, capacity=8, chunk_size=4):
 
 
 def check_quant_kernel_psum(fs):
-    from repro.kernels.ops import weighted_agg_quant, \
-        weighted_agg_quant_sharded
+    from repro.kernels.weighted_agg import (weighted_agg,
+                                            weighted_agg_sharded)
     K, D, chunk = 64, 600, 64
     coeffs = jax.random.uniform(jax.random.PRNGKey(0), (K,))
     flat = jax.random.normal(jax.random.PRNGKey(1), (K, D)) * 0.3
     payload, scales = quantize_chunked(flat, chunk=chunk)
-    want = np.asarray(weighted_agg_quant(coeffs, payload, scales,
-                                         chunk=chunk))
+    want = np.asarray(weighted_agg(coeffs, payload, scales, chunk=chunk))
     for kb in (None, 8):   # single-block K and streamed multi-block K
-        got = np.asarray(weighted_agg_quant_sharded(
+        got = np.asarray(weighted_agg_sharded(
             coeffs, payload, scales, chunk=chunk, mesh=fs.mesh,
             k_block=kb))
         err = float(np.abs(got - want).max())

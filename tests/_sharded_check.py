@@ -66,7 +66,8 @@ def make_sched(sharding, mode, agg="auto", capacity=7, chunk_size=16):
 
 
 def check_kernel_psum(fs):
-    from repro.kernels.ops import weighted_agg, weighted_agg_sharded
+    from repro.kernels.weighted_agg import (weighted_agg,
+                                            weighted_agg_sharded)
     K, D = 64, 600
     coeffs = jax.random.uniform(jax.random.PRNGKey(0), (K,))
     deltas = jax.random.normal(jax.random.PRNGKey(1), (K, D))

@@ -280,12 +280,11 @@ def test_admit_many_matches_single_admits():
 
 
 def test_trainer_plumbs_engine_options():
-    """Satellite: interpret/donate/with_metrics reach the RoundEngine the
-    trainer constructs (they were silently dropped before)."""
-    tr = make_trainer(make_clients(4), engine="plan", interpret=False,
-                      donate=False, with_metrics=True)
+    """Satellite: donate/with_metrics reach the RoundEngine the trainer
+    constructs (they were silently dropped before)."""
+    tr = make_trainer(make_clients(4), engine="plan", donate=False,
+                      with_metrics=True)
     eng = tr.engine
-    assert eng.interpret is False
     assert eng.donate is False
     assert eng.with_metrics is True
     # defaults still resolve (donate=None -> backend-dependent bool)

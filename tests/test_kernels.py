@@ -5,7 +5,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import ref
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.weighted_agg import weighted_agg
 
 KEY = jax.random.PRNGKey(0)
 
@@ -17,7 +19,7 @@ def test_weighted_agg_matches_ref(K, D, dtype):
     k1, k2 = jax.random.split(KEY)
     c = jax.random.uniform(k1, (K,), jnp.float32)
     d = jax.random.normal(k2, (K, D), dtype)
-    got = ops.weighted_agg(c, d)
+    got = weighted_agg(c, d)
     want = ref.weighted_agg_ref(c, d)
     np.testing.assert_allclose(got, want, rtol=2e-2 if dtype == jnp.bfloat16
                                else 1e-6, atol=1e-5)
@@ -31,7 +33,7 @@ def test_weighted_agg_tiled_k_matches_ref(K, k_block, D):
     k1, k2 = jax.random.split(KEY)
     c = jax.random.uniform(k1, (K,), jnp.float32)
     d = jax.random.normal(k2, (K, D), jnp.float32)
-    got = ops.weighted_agg(c, d, k_block=k_block)
+    got = weighted_agg(c, d, k_block=k_block)
     want = ref.weighted_agg_ref(c, d)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
@@ -43,7 +45,7 @@ def test_weighted_agg_auto_tiles_large_k():
     k1, k2 = jax.random.split(KEY)
     c = jax.random.uniform(k1, (K,), jnp.float32)
     d = jax.random.normal(k2, (K, 3000), jnp.float32)
-    got = ops.weighted_agg(c, d)
+    got = weighted_agg(c, d)
     want = ref.weighted_agg_ref(c, d)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
@@ -59,7 +61,7 @@ def test_weighted_agg_backend_aware_interpret_default():
     # and the public wrapper works with no interpret argument at all
     c = jax.random.uniform(KEY, (4,), jnp.float32)
     d = jax.random.normal(KEY, (4, 300), jnp.float32)
-    np.testing.assert_allclose(ops.weighted_agg(c, d),
+    np.testing.assert_allclose(weighted_agg(c, d),
                                ref.weighted_agg_ref(c, d),
                                rtol=1e-5, atol=1e-5)
 
@@ -71,9 +73,22 @@ def test_weighted_agg_property(K, D, block):
     rng = np.random.default_rng(K * 1000 + D)
     c = jnp.asarray(rng.uniform(0, 2, K), jnp.float32)
     d = jnp.asarray(rng.normal(size=(K, D)), jnp.float32)
-    got = ops.weighted_agg(c, d, block=block)
+    got = weighted_agg(c, d, block=block)
     want = ref.weighted_agg_ref(c, d)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("K,D,tiles", [
+    (4, 1, (128, 4)), (4, 127, (128, 4)), (4, 2049, (2048, 4)),
+    (100, 199_210, (2048, 32)),          # mnist-static: the paper's MLP
+    (4, 129_100_224, (2048, 4)),         # mamba2-fed: mamba2-130m
+])
+def test_tiles_pin_geometry(K, D, tiles):
+    """The kernel's own tile choice: a 2048-wide block shrunk to D
+    rounded up to 128 lanes, the whole client axis up to MAX_SINGLE_K
+    and 32-client slabs beyond it."""
+    from repro.kernels.weighted_agg import _tiles
+    assert _tiles(K, D) == tiles
 
 
 def _quantized(K, D, chunk, seed=0):
@@ -93,9 +108,8 @@ def test_weighted_agg_quant_matches_ref(K, k_block, D, chunk):
     chunk geometries, the streamed multi-block-K layout, and the
     auto-tiled large-K path."""
     c, payload, scales = _quantized(K, D, chunk)
-    got = ops.weighted_agg_quant(c, payload, scales, chunk=chunk,
-                                 k_block=k_block)
-    want = ref.weighted_agg_quant_ref(c, payload, scales, chunk=chunk)
+    got = weighted_agg(c, payload, scales, chunk=chunk, k_block=k_block)
+    want = ref.weighted_agg_ref(c, payload, scales, chunk=chunk)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
 
@@ -104,10 +118,9 @@ def test_weighted_agg_quant_block_not_chunk_aligned():
     """block is re-floored to a chunk multiple internally; a block
     smaller than chunk must still work (clamped up to one chunk)."""
     c, payload, scales = _quantized(4, 2048, 256)
-    want = ref.weighted_agg_quant_ref(c, payload, scales, chunk=256)
+    want = ref.weighted_agg_ref(c, payload, scales, chunk=256)
     for block in (300, 128, 512):
-        got = ops.weighted_agg_quant(c, payload, scales, chunk=256,
-                                     block=block)
+        got = weighted_agg(c, payload, scales, chunk=256, block=block)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-6)
 
@@ -115,9 +128,9 @@ def test_weighted_agg_quant_block_not_chunk_aligned():
 def test_weighted_agg_quant_rejects_bad_shapes():
     c, payload, scales = _quantized(4, 512, 128)
     with pytest.raises(ValueError):
-        ops.weighted_agg_quant(c, payload, scales[:, :-1], chunk=128)
+        weighted_agg(c, payload, scales[:, :-1], chunk=128)
     with pytest.raises(ValueError):
-        ops.weighted_agg_quant(c, payload[:, :-1], scales, chunk=128)
+        weighted_agg(c, payload[:, :-1], scales, chunk=128)
 
 
 def test_weighted_agg_quant_never_materializes_f32_deltas():
@@ -127,7 +140,7 @@ def test_weighted_agg_quant_never_materializes_f32_deltas():
     K, D, chunk = 8, 4096, 256
     c, payload, scales = _quantized(K, D, chunk)
     jaxpr = jax.make_jaxpr(
-        lambda c, p, s: ops.weighted_agg_quant(c, p, s, chunk=chunk))(
+        lambda c, p, s: weighted_agg(c, p, s, chunk=chunk))(
         c, payload, scales)
 
     def walk(jxp):
@@ -151,29 +164,6 @@ def test_weighted_agg_quant_never_materializes_f32_deltas():
     walk(jaxpr.jaxpr)
 
 
-@pytest.mark.parametrize("D", [128, 5000, 16384])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("alpha", [0.0, 1.0])
-def test_masked_sgd_matches_ref(D, dtype, alpha):
-    k1, k2 = jax.random.split(KEY)
-    w = jax.random.normal(k1, (D,), dtype)
-    g = jax.random.normal(k2, (D,), dtype)
-    ea = jnp.float32(0.05 * alpha)
-    got = ops.masked_sgd(w, g, ea)
-    want = ref.masked_sgd_ref(w, g, ea)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=2e-2 if dtype == jnp.bfloat16 else 1e-6,
-                               atol=1e-5)
-
-
-def test_masked_sgd_zero_alpha_is_identity():
-    w = jax.random.normal(KEY, (999,))
-    g = jax.random.normal(KEY, (999,))
-    out = ops.masked_sgd(w, g, jnp.float32(0.0))
-    np.testing.assert_allclose(out, w)
-
-
 @pytest.mark.parametrize("B,H,KV,S,hd", [
     (1, 2, 2, 128, 64),
     (2, 4, 2, 256, 64),
@@ -186,7 +176,7 @@ def test_flash_attention_matches_ref(B, H, KV, S, hd, dtype):
     q = jax.random.normal(ks[0], (B, H, S, hd), dtype)
     k = jax.random.normal(ks[1], (B, KV, S, hd), dtype)
     v = jax.random.normal(ks[2], (B, KV, S, hd), dtype)
-    got = ops.flash_attention(q, k, v)
+    got = flash_attention(q, k, v)
     kr = jnp.repeat(k, H // KV, 1)
     vr = jnp.repeat(v, H // KV, 1)
     want = ref.flash_attention_ref(q, kr, vr)
@@ -201,56 +191,9 @@ def test_flash_attention_non_causal():
     q = jax.random.normal(ks[0], (1, 2, 128, 64))
     k = jax.random.normal(ks[1], (1, 2, 128, 64))
     v = jax.random.normal(ks[2], (1, 2, 128, 64))
-    got = ops.flash_attention(q, k, v, causal=False)
+    got = flash_attention(q, k, v, causal=False)
     want = ref.flash_attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("Q,N,P", [(16, 8, 8), (64, 32, 16), (128, 64, 64)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ssd_intra_chunk_matches_ref(Q, N, P, dtype):
-    rng = np.random.default_rng(Q + N)
-    G = 6
-    cum = jnp.asarray(np.cumsum(
-        -rng.uniform(0.01, 0.1, (G, Q)), axis=-1), jnp.float32)
-    C = jnp.asarray(rng.normal(size=(G, Q, N)), dtype)
-    B = jnp.asarray(rng.normal(size=(G, Q, N)), dtype)
-    x = jnp.asarray(rng.normal(size=(G, Q, P)), dtype)
-    got = ops.ssd_intra_chunk(cum, C, B, x)
-    want = ref.ssd_intra_chunk_ref(cum, C, B, x)
-    if dtype == jnp.bfloat16:
-        # scores are cast to bf16 for the second MXU matmul (TPU-realistic);
-        # tolerance scales with the Q-term accumulation magnitude
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=6e-2, atol=0.4)
-    else:
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-5)
-
-
-def test_ssd_intra_chunk_matches_model_ssd():
-    """The kernel reproduces models/ssd.ssd_chunked's intra-chunk term:
-    single chunk, zero initial state => whole output is intra-chunk."""
-    from repro.models.ssd import ssd_chunked
-    rng = np.random.default_rng(0)
-    Bb, S, H, P, N = 1, 32, 2, 8, 4   # one chunk of Q=S, G=H groups
-    x = jnp.asarray(rng.normal(size=(Bb, S, H, P)), jnp.float32)
-    dt = jnp.asarray(rng.uniform(0.01, 0.2, (Bb, S, H)), jnp.float32)
-    A = jnp.asarray(-rng.uniform(0.5, 2.0, (H,)), jnp.float32)
-    Bm = jnp.asarray(rng.normal(size=(Bb, S, H, N)), jnp.float32)
-    Cm = jnp.asarray(rng.normal(size=(Bb, S, H, N)), jnp.float32)
-    y_model, _ = ssd_chunked(x, dt, A, Bm, Cm, chunk=S)  # G=H
-    # kernel view: one cell per (b, head)
-    cum = jnp.cumsum(dt * A[None, None, :], axis=1)      # (Bb,S,H)
-    cum_g = jnp.moveaxis(cum, -1, 1).reshape(Bb * H, S)
-    C_g = jnp.moveaxis(Cm, 2, 1).reshape(Bb * H, S, N)
-    B_g = jnp.moveaxis(Bm, 2, 1).reshape(Bb * H, S, N)
-    xdt = x * dt[..., None]
-    x_g = jnp.moveaxis(xdt, 2, 1).reshape(Bb * H, S, P)
-    y_k = ops.ssd_intra_chunk(cum_g, C_g, B_g, x_g)
-    y_k = jnp.moveaxis(y_k.reshape(Bb, H, S, P), 1, 2)
-    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_model),
-                               rtol=2e-3, atol=2e-3)
 
 
 # --- ssd_scan: the whole chunked SSD as one op with its backward pass -----
@@ -309,15 +252,36 @@ def _assert_close(got, want, rtol):
                                rtol=rtol, atol=rtol * scale)
 
 
-@pytest.mark.parametrize("H,P,G,N", [
-    (4, 64, 1, 128), (4, 64, 2, 16), (8, 32, 2, 16), (2, 128, 1, 16),
-    (20, 64, 1, 16)])
-def test_ssd_scan_matches_ssd_chunked_and_recurrence(H, P, G, N):
+# the mixer hands x, B and C over in the model's dtype; a bf16 case
+# compares with the oracles on the same bf16-rounded inputs in f32, its
+# bf16 y, dy and d(x, B, C) rounded once more: about 5 unit roundoffs
+# (2^-9) of the largest magnitude
+TOL = {jnp.float32: (2e-5, 1e-4), jnp.bfloat16: (1e-2, 1e-2)}
+
+
+def _in_dtype(args, dtype):
+    """x, B and C of _scan_inputs in dtype, the rest as they are."""
+    return tuple(a.astype(dtype) if i in (0, 3, 4) else a
+                 for i, a in enumerate(args))
+
+
+SCAN_SHAPES = [(4, 64, 1, 128), (4, 64, 2, 16), (8, 32, 2, 16),
+               (2, 128, 1, 16), (20, 64, 1, 16)]
+
+
+@pytest.mark.parametrize("H,P,G,N,dtype", [
+    pytest.param(*shape, dtype, id="-".join(map(str, shape)) + suffix)
+    for dtype, suffix in ((jnp.float32, ""), (jnp.bfloat16, "-bf16"))
+    for shape in SCAN_SHAPES])
+def test_ssd_scan_matches_ssd_chunked_and_recurrence(H, P, G, N, dtype):
     """y, h_last and d/d(x, dt, A, B, C, h0) of the fused scan equal the
     jnp chunked SSD and the per-position recurrence: two chunks of Q=128,
     heads of P=32/64/128 filling 128-lane tiles, groups of 2-20 heads
     (20: a group over several head steps)."""
-    args = _scan_inputs(1, 2 * SCAN_Q, H, P, G, N, seed=H + P + G + N)
+    args = _in_dtype(_scan_inputs(1, 2 * SCAN_Q, H, P, G, N,
+                                  seed=H + P + G + N), dtype)
+    ref_args = tuple(a.astype(jnp.float32) for a in args)
+    rtol, gtol = TOL[dtype]
     rng = np.random.default_rng(1)
     w = jnp.asarray(rng.normal(size=args[0].shape), jnp.float32)
     wh = jnp.asarray(rng.normal(size=args[5].shape), jnp.float32)
@@ -325,11 +289,32 @@ def test_ssd_scan_matches_ssd_chunked_and_recurrence(H, P, G, N):
     grads = jax.grad(_scan_loss(_scan_fused, w, wh), argnums=range(6))(
         *args)
     for oracle in (_scan_of_ssd_chunked, _scan_of_recurrence):
-        for g, h in zip(got, oracle(*args)):
-            _assert_close(g, h, 2e-5)
-        want = jax.grad(_scan_loss(oracle, w, wh), argnums=range(6))(*args)
+        for g, h in zip(got, oracle(*ref_args)):
+            _assert_close(g.astype(jnp.float32), h, rtol)
+        want = jax.grad(_scan_loss(oracle, w, wh), argnums=range(6))(
+            *ref_args)
         for g, h in zip(grads, want):
-            _assert_close(g, h, 1e-4)
+            _assert_close(g.astype(jnp.float32), h, gtol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("N,P", [(8, 8), (32, 16), (64, 64), (128, 64)])
+def test_ssd_scan_single_chunk_is_the_intra_chunk_term(N, P, dtype):
+    """One chunk from a zero state: y is the intra-chunk term alone,
+    ((C B^T) * L) @ (x dt), which ref.ssd_intra_chunk_ref computes per
+    head; H = 128/P heads of one group fill one lane tile."""
+    H = 128 // P
+    x, dt, A, B, C, _ = _in_dtype(_scan_inputs(1, SCAN_Q, H, P, 1, N,
+                                               seed=N + P), dtype)
+    h0 = jnp.zeros((1, 1, H, P, N), jnp.float32)
+    y, _ = _scan_fused(x, dt, A, B, C, h0)
+    f32 = lambda a: a.astype(jnp.float32)
+    cum = jnp.cumsum(dt * A, axis=1)[0].T                       # (H, Q)
+    xdt = jnp.moveaxis(f32(x)[0] * dt[0][..., None], 1, 0)      # (H, Q, P)
+    Cg, Bg = (jnp.broadcast_to(f32(a)[0, :, 0], (H, SCAN_Q, N))
+              for a in (C, B))
+    want = ref.ssd_intra_chunk_ref(cum, Cg, Bg, xdt)
+    _assert_close(jnp.moveaxis(f32(y)[0], 1, 0), want, TOL[dtype][0])
 
 
 @pytest.mark.parametrize("transform", ["vmap", "checkpoint"])

@@ -60,18 +60,37 @@ def test_fed_sharding_requires_named_axis():
 
 
 def test_weighted_agg_sharded_rejects_ragged_client_axis():
-    from repro.kernels.ops import weighted_agg_sharded
+    from repro.kernels.weighted_agg import weighted_agg_sharded
     fs = make_fed_sharding(1)
     # a 1-device mesh can't produce the error, so check the guard directly
     with pytest.raises(ValueError, match="not divisible"):
-        from repro.kernels.weighted_agg import weighted_agg_sharded as raw
-
         class FakeMesh:
             shape = {"data": 2}
-        raw(jnp.ones(3), jnp.ones((3, 8)), mesh=FakeMesh())
+        weighted_agg_sharded(jnp.ones(3), jnp.ones((3, 8)), mesh=FakeMesh())
     # happy path on the real mesh
     out = weighted_agg_sharded(jnp.ones(4), jnp.ones((4, 10)), mesh=fs.mesh)
     np.testing.assert_allclose(np.asarray(out), 4.0, rtol=1e-6)
+
+
+@pytest.mark.parametrize("K", [4, 70])          # 70: the streamed K slabs
+@pytest.mark.parametrize("wire", ["f32", "bf16", "int8"])
+def test_weighted_agg_sharded_equals_weighted_agg(wire, K):
+    """The shard_map wrapper (local launch + psum) returns what the local
+    kernel does, on every wire."""
+    from repro.core.compression import quantize_chunked
+    from repro.kernels.weighted_agg import weighted_agg, weighted_agg_sharded
+    fs = make_fed_sharding(1)
+    rng = np.random.default_rng(K)
+    coeffs = jnp.asarray(rng.uniform(0, 1, K), jnp.float32)
+    flat = jnp.asarray(rng.normal(size=(K, 700)), jnp.float32)
+    args, kw = (flat,), {}
+    if wire == "bf16":
+        args = (flat.astype(jnp.bfloat16),)
+    elif wire == "int8":
+        args, kw = quantize_chunked(flat, chunk=128), {"chunk": 128}
+    want = weighted_agg(coeffs, *args, **kw)
+    got = weighted_agg_sharded(coeffs, *args, mesh=fs.mesh, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # -- 1-device mesh: sharded path == unsharded path ----------------------------

@@ -290,9 +290,9 @@ def _sampled_chunk_hlo(**kw):
 
 
 @pytest.mark.parametrize("kw, scopes", [
-    (dict(agg="flat", interpret=True),
+    (dict(agg="flat"),
      {"sample", "gather", "local_sgd", "flatten", "aggregate"}),
-    (dict(agg="flat", interpret=True, compression="int8"),
+    (dict(agg="flat", compression="int8"),
      {"sample", "gather", "local_sgd", "flatten", "aggregate"}),
     (dict(agg="tree"), {"sample", "gather", "local_sgd", "aggregate"}),
     (dict(engine_mode="client_sequential"),
@@ -307,11 +307,11 @@ def test_round_phases_are_named_scopes(kw, scopes):
 
 
 @pytest.mark.parametrize("kw", [
-    dict(agg="flat", interpret=True),
-    dict(agg="flat", interpret=True, compression="int8"),
+    dict(agg="flat"),
+    dict(agg="flat", compression="int8"),
 ], ids=["flat", "flat-int8"])
 def test_aggregation_kernel_names_its_operand_pad(kw):
-    """The weighted_agg kernels' zero pad carries the kernel module's own
+    """The weighted_agg kernel's zero pad carries the kernel module's own
     scope, inside the round's fed.aggregate, and no phase name of the
     round."""
     import re

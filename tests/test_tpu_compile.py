@@ -14,8 +14,10 @@ described chip cannot be read back without one.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.kernels import ssd_chunk, weighted_agg as wa
 
@@ -23,7 +25,7 @@ CHUNK = 256
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -38,9 +40,19 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topology):
+    return Mesh(np.array(topology.devices).reshape(4), ("data",))
 
 
 @pytest.fixture(scope="module")
@@ -72,37 +84,49 @@ def test_widths_are_the_published_ones(widths):
 # K=100 slots (the paper's 100 devices) takes the K-tiled grid.  At
 # mamba2-130m's width a (100, D) delta matrix alone exceeds the chip's
 # 16 GB, which the compiler reports, so the wide cases keep K small.
+# int8 cases are the quantized wire: codes of a whole number of chunks
+# plus their f32 scales.
 @pytest.mark.parametrize("model,K,dtype", [
     ("mnist_mlp", 6, jnp.float32), ("mnist_mlp", 20, jnp.bfloat16),
     ("mnist_mlp", 100, jnp.float32), ("mamba2-130m", 6, jnp.float32),
-    ("mamba2-130m", 20, jnp.bfloat16)])
+    ("mamba2-130m", 20, jnp.bfloat16), ("mnist_mlp", 6, jnp.int8),
+    ("mnist_mlp", 100, jnp.int8), ("mamba2-130m", 6, jnp.int8)])
 def test_weighted_agg_compiles(one_chip, widths, model, K, dtype):
-    D = widths[model]
-    _compile(lambda c, d: wa.weighted_agg(c, d, interpret=False),
-             jax.ShapeDtypeStruct((K,), jnp.float32, sharding=one_chip),
-             jax.ShapeDtypeStruct((K, D), dtype, sharding=one_chip))
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    D, kw = widths[model], {}
+    operands = (sds((K, D), dtype),)
+    if dtype == jnp.int8:
+        n_chunks = -(-D // CHUNK)
+        operands = (sds((K, n_chunks * CHUNK), jnp.int8),
+                    sds((K, n_chunks), jnp.float32))
+        kw = {"chunk": CHUNK}
+    _compile(lambda c, *ops: wa.weighted_agg(c, *ops, interpret=False, **kw),
+             sds((K,), jnp.float32), *operands)
 
 
-@pytest.mark.parametrize("model,K", [("mnist_mlp", 6), ("mnist_mlp", 100),
-                                     ("mamba2-130m", 6)])
-def test_weighted_agg_quant_compiles(one_chip, widths, model, K):
-    n_chunks = -(-widths[model] // CHUNK)
-    _compile(lambda c, p, s: wa.weighted_agg_quant(c, p, s, chunk=CHUNK,
-                                                   interpret=False),
-             jax.ShapeDtypeStruct((K,), jnp.float32, sharding=one_chip),
-             jax.ShapeDtypeStruct((K, n_chunks * CHUNK), jnp.int8,
-                                  sharding=one_chip),
-             jax.ShapeDtypeStruct((K, n_chunks), jnp.float32,
-                                  sharding=one_chip))
-
-
-def test_ssd_intra_chunk_compiles(one_chip):
-    G, Q, N, P = 48, 128, 128, 64    # mamba2-130m: d_state 128, head 64
-    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
-                                              sharding=one_chip)
-    _compile(lambda cum, C, B, x: ssd_chunk.ssd_intra_chunk(
-        cum, C, B, x, interpret=False),
-        sds(G, Q), sds(G, Q, N), sds(G, Q, N), sds(G, Q, P))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8])
+def test_weighted_agg_sharded_compiles(four_chips, widths, monkeypatch,
+                                       dtype):
+    """The client-sharded reduction over a described four-chip mesh:
+    each chip's launch over its 25 of 100 clients holds the kernel, and
+    a cross-chip all-reduce finishes it.  The sharded wrapper takes no
+    ``interpret``, so the kernel is steered to Mosaic here."""
+    monkeypatch.setattr(wa, "resolve_interpret",
+                        lambda i: False if i is None else bool(i))
+    sds = lambda shape, dt, *spec: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(four_chips, P("data", *spec)))
+    K, D, kw = 100, widths["mnist_mlp"], {}
+    operands = (sds((K, D), dtype, None),)
+    if dtype == jnp.int8:
+        n_chunks = -(-D // CHUNK)
+        operands = (sds((K, n_chunks * CHUNK), jnp.int8, None),
+                    sds((K, n_chunks), jnp.float32, None))
+        kw = {"chunk": CHUNK}
+    text = jax.jit(lambda c, *ops: wa.weighted_agg_sharded(
+        c, *ops, mesh=four_chips, **kw)).lower(
+        sds((K,), jnp.float32), *operands).compile().as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
 
 
 def test_ssd_scan_fwd_and_bwd_compile(one_chip):
