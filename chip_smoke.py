@@ -15,7 +15,10 @@ Phases:
            compiled (not interpreted) kernels; 100 slots take the
            K-tiled kernel grid.  Checks, per wire:
              kernel  one aggregation call at (K=100, D=199,210) against
-                     the jnp reference in kernels/ref.py;
+                     the jnp reference in kernels/ref.py; on the `none`
+                     wire also one per parameter leaf (K=100, each leaf
+                     where it lies) and at mamba2-130m's awkward leaves
+                     and ragged row tiles and K slabs;
              parity  the trained params against a plain reference: the
                      same seeds with agg="tree" (per-leaf jnp).  Both
                      sides run with every XLA matmul at "highest"
@@ -132,8 +135,9 @@ def paper_run(clients, *, wire: str, agg: str, highest: bool,
     return tr, spans
 
 
-def kernel_parity(wire: str, K: int, D: int) -> float:
-    """One aggregation kernel call against kernels/ref.py."""
+def kernel_parity(wire: str, K: int, shape: tuple, **tiles) -> float:
+    """One aggregation kernel call over a (K, *shape) operand against
+    kernels/ref.py; ``tiles`` forces ragged tiles (block, k_block)."""
     import jax
 
     from repro.core.compression import quantize_chunked
@@ -142,14 +146,21 @@ def kernel_parity(wire: str, K: int, D: int) -> float:
 
     kc, kd = jax.random.split(jax.random.PRNGKey(7))
     coeffs = jax.random.uniform(kc, (K,))
-    deltas = 0.01 * jax.random.normal(kd, (K, D))
+    deltas = 0.01 * jax.random.normal(kd, (K,) + tuple(shape))
     args, kw = (deltas,), {}
     if wire == "int8":
         args, kw = quantize_chunked(deltas, chunk=256), {"chunk": 256}
     with jax.default_matmul_precision("highest"):
-        got = weighted_agg(coeffs, *args, **kw)
+        got = weighted_agg(coeffs, *args, **kw, **tiles)
         want = ref.weighted_agg_ref(coeffs, *args, **kw)
     return max_diff(got, want)
+
+
+# leaves the f32 wire reduces where they lie, beside the paper MLP's
+# own: mamba2-130m's lane-sparse and second-minor-4 leaves, a ragged
+# last row tile and a ragged last K slab
+LEAF_CASES = ((4, (24, 768, 24), {}), (4, (24, 4, 1792), {}),
+              (5, (20, 130), {"block": 8}), (7, (16, 300), {"k_block": 3}))
 
 
 def paper_phase(n_clients: int = PAPER_CLIENTS,
@@ -193,10 +204,16 @@ def paper_phase(n_clients: int = PAPER_CLIENTS,
                 f"+ {rounds // 2} rounds) span2_s={spans[1]:.3f} "
                 f"({rounds - rounds // 2} rounds, warm) "
                 f"compile_s~{spans[0] - spans[1]:.3f}")
-        k_err = kernel_parity(wire, n_clients, d_total)
-        log(f"paper[{wire}] kernel K={n_clients} D={d_total} "
-            f"max_abs_err={k_err:.3e} (bound {KERNEL_BOUND:g})")
-        check(k_err <= KERNEL_BOUND, f"{wire} kernel differs by {k_err}")
+        cases = [(n_clients, (d_total,), {})]
+        if wire == "none":
+            cases += [(n_clients, x.shape, {})
+                      for x in jax.tree.leaves(tr.params)] + list(LEAF_CASES)
+        for K, shape, tiles in cases:
+            k_err = kernel_parity(wire, K, shape, **tiles)
+            log(f"paper[{wire}] kernel K={K} shape={shape} {tiles} "
+                f"max_abs_err={k_err:.3e} (bound {KERNEL_BOUND:g})")
+            check(k_err <= KERNEL_BOUND,
+                  f"{wire} kernel {shape} differs by {k_err}")
         par = max_diff(runs["system_highest"].params,
                        runs["reference"].params)
         drift = max_diff(runs["system"].params, runs["reference"].params)
@@ -215,7 +232,7 @@ def paper_phase(n_clients: int = PAPER_CLIENTS,
 # -- lm ---------------------------------------------------------------------
 # A few clients at a seq and batch that fit one 16 GB chip: the compile
 # for a described v5e puts one such round at ~5.5 GB of temporaries, and
-# capacity = clients keeps the (C, D_total) f32 delta buffer at 4 rows.
+# capacity = clients keeps the f32 client deltas at 4 rows.
 LM_ARGV = ("--arch", "mamba2-130m", "--full", "--rounds", "4",
            "--clients", "4", "--capacity", "4", "--samples", "8",
            "--seq", "1024", "--batch", "4", "--local-epochs", "2",

@@ -49,8 +49,9 @@ def aggregate_deltas(params, deltas, coeffs):
     """w + sum_k c_k delta_k over a stacked client axis.
 
     deltas: pytree with leading client dim (C, ...); coeffs: (C,).
-    This is the jnp reference path; aggregate_deltas_flat is the fused
-    single-launch Pallas path used by the device-resident round engine.
+    This is the jnp reference path; aggregate_deltas_flat is the Pallas
+    path (one weighted_agg launch per leaf) used by the device-resident
+    round engine.
     """
     def upd(p, d):
         c = coeffs.astype(jnp.float32).reshape((-1,) + (1,) * (d.ndim - 1))
@@ -94,47 +95,71 @@ def _apply_flat(params, agg):
     return jax.tree.unflatten(treedef, outs)
 
 
+def agg_launches(params, compression=None) -> int:
+    """weighted_agg launches aggregate_deltas_flat makes a round: one per
+    parameter leaf on the f32 and bf16 wires, one over the flattened
+    codes on the int8 wires."""
+    from repro.core.compression import resolve_compression
+    if resolve_compression(compression).quantized:
+        return 1
+    return len(jax.tree.leaves(params))
+
+
 def aggregate_deltas_flat(params, deltas, coeffs, *, sharding=None,
                           compression=None):
-    """Same contract as aggregate_deltas, but the whole model is flattened
-    into a single (C, D_total) buffer and reduced with ONE weighted_agg
-    Pallas launch (instead of one scaled-add tree per leaf).
+    """Same contract as aggregate_deltas, reduced by the weighted_agg
+    Pallas kernel instead of one scaled-add tree per leaf.
+
+    On the f32 and bf16 wires each stacked delta leaf (C, ...) is reduced
+    where it lies, one launch per leaf (``agg_launches``): no (C, D_total)
+    buffer and no padded copy exists, and w + agg is a plain add per
+    leaf.  bf16 is a cast of each leaf first (the kernel reduces any
+    float dtype in f32).
+
+    The int8 kinds flatten the leaves into one (C, D_total) buffer,
+    quantize it on-device on the flattened-leaf chunk grid that the
+    sequential mode and aggregate_deltas_compressed_ref share, and reduce
+    the codes with ONE launch that fuses dequantization.
 
     sharding: an optional fed.sharding.FedSharding whose mesh shards the
-    client axis — each device then reduces its own (C/n, D_total) slab
-    locally and a psum epilogue replicates the result (the cross-device
-    path of the sharded round engine).
-
-    compression: optional CompressionSpec/str (core.compression).  The
-    int8 kinds quantize the flat buffer on-device and the kernel fuses
-    dequantization into the reduction — under sharding the codes and
-    scales are what shard over the federation axes, so only compressed
-    bytes (plus one f32 psum) cross the client dim.  bf16 is a plain cast
-    (the kernel reduces any float dtype in f32)."""
+    client axis — each device then reduces its own clients' leaves (or
+    codes and scales: only compressed bytes shard over the federation
+    axes) locally and one psum epilogue replicates the result (the
+    cross-device path of the sharded round engine)."""
     from repro.core.compression import compress_flat, resolve_compression
     # kernels never import core: no cycle
     from repro.kernels.weighted_agg import (weighted_agg,
                                             weighted_agg_sharded)
 
     spec = resolve_compression(compression)
-    flat = flatten_client_deltas(deltas)
-    D = flat.shape[1]
-    operands, kw = (flat,), {}
     if spec.quantized:
+        flat = flatten_client_deltas(deltas)
         with jax.named_scope("fed.flatten"):
-            operands, kw = compress_flat(flat, spec), {"chunk": spec.chunk}
-    elif spec.kind == "bf16":
+            codes, scales = compress_flat(flat, spec)
+        with jax.named_scope("fed.aggregate"):
+            if sharding is None:
+                agg = weighted_agg(coeffs, codes, scales, chunk=spec.chunk)
+            else:
+                # each shard owns its own clients' compressed bytes
+                agg = weighted_agg_sharded(
+                    coeffs, sharding.constrain_client(codes),
+                    sharding.constrain_client(scales), chunk=spec.chunk,
+                    mesh=sharding.mesh, axis=sharding.axis)
+            return _apply_flat(params, agg[:flat.shape[1]])
+    leaves, treedef = jax.tree.flatten(deltas)
+    if spec.kind == "bf16":
         with jax.named_scope("fed.flatten"):
-            operands = (flat.astype(jnp.bfloat16),)
+            leaves = [x.astype(jnp.bfloat16) for x in leaves]
     with jax.named_scope("fed.aggregate"):
         if sharding is None:
-            agg = weighted_agg(coeffs, *operands, **kw)
+            aggs = [weighted_agg(coeffs, x) for x in leaves]
         else:
-            # each shard owns its own clients' (compressed) bytes
-            operands = [sharding.constrain_client(x) for x in operands]
-            agg = weighted_agg_sharded(coeffs, *operands, mesh=sharding.mesh,
-                                       axis=sharding.axis, **kw)
-        return _apply_flat(params, agg[:D])
+            aggs = weighted_agg_sharded(
+                coeffs, [sharding.constrain_client(x) for x in leaves],
+                mesh=sharding.mesh, axis=sharding.axis)
+        return jax.tree.map(
+            lambda p, a: (p.astype(jnp.float32) + a).astype(p.dtype),
+            params, jax.tree.unflatten(treedef, aggs))
 
 
 def aggregate_deltas_compressed_ref(params, deltas, coeffs, compression):

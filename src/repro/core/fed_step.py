@@ -114,8 +114,8 @@ def fed_round_parallel(loss_fn, params, batches, alpha, coeffs, eta, *,
     Returns (new_params, metrics).
 
     agg selects the aggregation layout: "tree" is the per-leaf jnp
-    reference; "flat" flattens the delta pytree into one (C, D_total)
-    buffer and reduces it with a single weighted_agg Pallas launch.
+    reference; "flat" reduces each delta leaf where it lies with the
+    weighted_agg Pallas kernel (aggregate_deltas_flat).
     with_metrics=False skips the delta-norm reduction (hot-loop mode).
 
     sharding: optional fed.sharding.FedSharding — the client axis of

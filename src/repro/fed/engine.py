@@ -25,10 +25,10 @@ chunked ``lax.scan``:
   * R rounds run per host dispatch via ``lax.scan`` over power-of-two
     chunk sizes (bounded compile cache), with ``params`` donated to the
     chunk call on backends that support buffer donation;
-  * aggregation uses the pytree-flat path: the delta pytree is flattened
-    to one (C, D_total) buffer and reduced with a single weighted_agg
-    Pallas launch per round (``agg="flat"``), or the per-leaf jnp tree
-    path (``agg="tree"``);
+  * aggregation uses the weighted_agg Pallas kernel (``agg="flat"``):
+    one launch per delta leaf, each reduced where it lies (the int8
+    wires: one launch over the flattened, quantized leaves), or the
+    per-leaf jnp tree path (``agg="tree"``);
   * with ``sharding=FedSharding(...)`` the client/slot axis of every
     buffer is sharded over the mesh's federation axis: local epochs run
     device-parallel and the delta reduction ends in a cross-device
@@ -57,7 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.aggregation import scheme_coefficients
+from repro.core.aggregation import agg_launches, scheme_coefficients
 from repro.core.compression import resolve_compression, wire_bytes
 from repro.core.fed_step import fed_round_parallel, fed_round_sequential
 from repro.fed.bank import pad_rows
@@ -445,6 +445,9 @@ class RoundEngine:
         tel.gauge("engine_ssd_fused_layers",
                   "SSD layers that run as the fused Pallas scan").set(
             task.ssd_fused_layers)
+        # set with _d_total, from the first span's params
+        self._g_agg_launches = tel.gauge(
+            "engine_agg_launches", "weighted_agg launches a round")
 
     def _param_specs(self, params):
         """The task's per-leaf PartitionSpecs (None => replicated),
@@ -672,9 +675,10 @@ class RoundEngine:
                 param_specs=pspecs, compression=self.compression)
         else:
             # model-spec'd params must take the tree path: the flat
-            # layout concatenates mixed-sharding delta leaves (the GSPMD
-            # pattern safe_concat exists for) and materializes the
-            # reduced (D_total,) vector replicated over the model axes
+            # layout's shard_map returns each reduced leaf replicated
+            # over the model axes (and the int8 wires concatenate
+            # mixed-sharding delta leaves, the GSPMD pattern safe_concat
+            # exists for)
             agg = "tree" if pspecs is not None else self.agg
             new_params, m = fed_round_parallel(
                 self._step_loss, params, rows, alpha, coeffs, eta,
@@ -683,6 +687,16 @@ class RoundEngine:
                 compression=self.compression)
         return new_params, {"s": s, "eta": eta,
                             "delta_norm": m["delta_norm"]}
+
+    def _agg_launches(self, params) -> int:
+        """weighted_agg launches a round: the flat layout's
+        (core.aggregation.agg_launches) where the round takes it, 0 on
+        the tree path, the client-sequential mode and model-spec'd
+        params."""
+        flat = (self.mode == "client_parallel" and self.agg == "flat"
+                and (self.sharding is None
+                     or self._param_specs(params) is None))
+        return agg_launches(params, self.compression) if flat else 0
 
     def _get_fn(self, R: int, sampled: bool):
         cache_key = (R, sampled)
@@ -767,6 +781,7 @@ class RoundEngine:
             self._d_total = sum(
                 int(np.prod(np.shape(leaf)))
                 for leaf in jax.tree.leaves(params))
+            self._g_agg_launches.set(self._agg_launches(params))
         # no-op for args already device-resident in the right dtype
         # (the StreamScheduler's cached span args) — an unconditional
         # jnp.asarray costs ~60us of python per arg per span

@@ -6,14 +6,14 @@ import jax.numpy as jnp
 
 
 def weighted_agg_ref(coeffs, deltas, scales=None, *, chunk=None):
-    """(K,), (K,D) -> (D,) f32.  With scales (K, D/chunk) f32, deltas are
-    int8 codes: dequantize, then reduce."""
-    K, D = deltas.shape
+    """(K,), (K, *shape) -> shape f32.  With scales (K, D/chunk) f32,
+    deltas are (K, D) int8 codes: dequantize, then reduce."""
     deltas = deltas.astype(jnp.float32)
     if scales is not None:
+        K, D = deltas.shape
         deltas = (deltas.reshape(K, D // chunk, chunk)
                   * scales[..., None]).reshape(K, D)
-    return jnp.einsum("k,kd->d", coeffs.astype(jnp.float32), deltas)
+    return jnp.einsum("k,k...->...", coeffs.astype(jnp.float32), deltas)
 
 
 def ssd_intra_chunk_ref(cum, C, B, xdt):

@@ -124,6 +124,121 @@ def test_flat_aggregation_matches_tree(dtypes):
                                    else 1e-5, atol=1e-3)
 
 
+def _model_tree(name):
+    """A model's parameter tree: the paper's MNIST 2NN (6 f32 leaves) or
+    mamba2-130m's 15 leaves at reduced widths, bf16 matrices beside f32
+    norms and SSD scalars as at its published widths."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.paper import MNIST_MLP
+    key = jax.random.PRNGKey(3)
+    if name == "mnist-mlp":
+        return init_small(key, MNIST_MLP)
+    cfg = dataclasses.replace(get_config("mamba2-130m").reduced(),
+                              dtype="bfloat16")
+    return LMTask(cfg, seq_len=8).init_params(key)
+
+
+def _stacked_deltas(params, C):
+    """f32 client deltas (C, ...) per leaf, as local_sgd returns them."""
+    return jax.tree.map(
+        lambda p: jax.random.normal(jax.random.fold_in(
+            jax.random.PRNGKey(4), p.size), (C,) + p.shape), params)
+
+
+@pytest.mark.parametrize("tree,wire", [("mnist-mlp", "none"),
+                                       ("mamba2", "none"),
+                                       ("mamba2", "bf16")])
+def test_flat_aggregation_matches_tree_on_model_trees(tree, wire):
+    """aggregate_deltas_flat, one launch per leaf where the leaf lies, ==
+    aggregate_deltas on the cells' trees; the bf16 wire == the tree
+    reduction of the bf16-rounded deltas."""
+    params = _model_tree(tree)
+    C = 7
+    deltas = _stacked_deltas(params, C)
+    coeffs = jax.random.uniform(jax.random.PRNGKey(5), (C,))
+    sent = deltas if wire == "none" else jax.tree.map(
+        lambda d: d.astype(jnp.bfloat16), deltas)
+    want = aggregate_deltas(params, sent, coeffs)
+    got = aggregate_deltas_flat(params, deltas, coeffs, compression=wire)
+    assert len(jax.tree.leaves(got)) == (6 if tree == "mnist-mlp" else 15)
+    for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        assert w.dtype == g.dtype and w.shape == g.shape
+        # a bf16 leaf may round the same f32 sum to the neighbouring ulp
+        tol = 1e-2 if w.dtype == jnp.bfloat16 else 1e-5
+        np.testing.assert_allclose(np.asarray(w, np.float32),
+                                   np.asarray(g, np.float32),
+                                   rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("tree", ["mnist-mlp", "mamba2"])
+def test_flat_aggregation_never_concatenates_or_pads_deltas(tree):
+    """On the f32 wire no (C, ...) delta is concatenated or padded outside
+    the kernel: each leaf enters its launch where it lies."""
+    params = _model_tree(tree)
+    C = 7
+    deltas = _stacked_deltas(params, C)
+    jaxpr = jax.make_jaxpr(aggregate_deltas_flat)(
+        params, deltas, jnp.ones(C, jnp.float32))
+    launches = []
+
+    def walk(jxp):
+        for eqn in jxp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                launches.append(eqn)
+                continue
+            if eqn.primitive.name in ("concatenate", "pad"):
+                for v in eqn.invars:
+                    shape = getattr(getattr(v, "aval", None), "shape", ())
+                    assert not (len(shape) >= 2 and shape[0] == C), (
+                        f"{eqn.primitive.name} of a {shape} delta")
+            for val in eqn.params.values():
+                if hasattr(val, "eqns"):                # Jaxpr
+                    walk(val)
+                elif hasattr(val, "jaxpr"):             # ClosedJaxpr
+                    walk(val.jaxpr)
+    walk(jaxpr.jaxpr)
+    assert len(launches) == len(jax.tree.leaves(params))
+
+
+@pytest.mark.parametrize("model,wire,agg,launches", [
+    ("mnist-mlp", "none", "flat", 6), ("mnist-mlp", "int8", "flat", 1),
+    ("mnist-mlp", "none", "tree", 0), ("mamba2", "none", "flat", 15)])
+def test_agg_launches_gauge(model, wire, agg, launches):
+    """engine_agg_launches, set with the first span: one weighted_agg
+    launch per parameter leaf on the f32 wire (the paper's MLP: 6; an LM
+    task: its leaf count), one on the int8 wire, none on the tree path."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.paper import MNIST_MLP
+    from repro.obs.telemetry import Telemetry
+
+    rng = np.random.default_rng(0)
+    if model == "mnist-mlp":
+        kw = dict(loss_fn=make_loss_fn(MNIST_MLP),
+                  clients=mlp28_clients([12, 9]))
+        params = init_small(jax.random.PRNGKey(0), MNIST_MLP)
+    else:
+        cfg = dataclasses.replace(get_config("mamba2-130m").reduced(),
+                                  dtype="bfloat16")
+        task = LMTask(cfg, seq_len=8)
+        kw = dict(task=task, clients=[
+            Client(x=task.token_stream(rng, n=3, domain=d),
+                   trace=TRACES[d]) for d in range(2)])
+        params = task.init_params(jax.random.PRNGKey(0))
+    tel = Telemetry()
+    eng = RoundEngine(local_epochs=1, batch_size=2, agg=agg,
+                      compression=wire, telemetry=tel, **kw)
+    eng.run_span(params, 0, 1, p=np.full(2, 0.5, np.float32),
+                 active=np.ones(2, np.float32), lr_shift_tau=0,
+                 reboot_tau0=np.zeros(2, np.int32),
+                 reboot_boost=np.ones(2, np.float32),
+                 key=jax.random.PRNGKey(1))
+    assert tel.registry.gauge("engine_agg_launches").value == launches
+
+
 def test_engine_device_sampling_distribution():
     """On-device inverse-CDF sampling reproduces Trace.sample_s's law:
     per-client mean of s within a few stderr of the host sampler."""

@@ -25,6 +25,40 @@ def test_weighted_agg_matches_ref(K, D, dtype):
                                else 1e-6, atol=1e-5)
 
 
+@pytest.mark.parametrize("shape,kw", [
+    ((100, 784, 200), {}), ((100, 200, 10), {}), ((100, 10), {}),  # mnist
+    ((4, 24, 768, 24), {}), ((4, 24, 4, 1792), {}),              # mamba2
+    ((4, 24, 24), {}),
+    ((5, 20, 130), {"block": 8}),        # ragged last row tile
+    ((7, 16, 300), {"k_block": 3}),      # ragged last K slab
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple)
+    else "-".join(f"{k}{n}" for k, n in v.items()) or "auto")
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_weighted_agg_leaf_matches_ref(shape, kw, dtype):
+    """One stacked leaf (K, *shape) of rank 2-4 reduced where it lies ==
+    the jnp reduction, at the cells' awkward leaves (minor dims of 10,
+    24 and 200; a second-minor of 4) and with ragged row tiles and K
+    slabs, whose garbage rows and clients must not enter the sum."""
+    rng = np.random.default_rng(sum(shape))
+    c = jnp.asarray(rng.uniform(0, 2, shape[0]), jnp.float32)
+    d = jnp.asarray(rng.normal(size=shape), dtype)
+    got = weighted_agg(c, d, **kw)
+    want = ref.weighted_agg_ref(c, d)
+    assert got.shape == shape[1:] and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("case", range(4))
+def test_chip_smoke_leaf_cases_run_off_chip(case):
+    """chip_smoke.py's kernel check at each of its leaf cases (forced
+    ragged tiles among them) runs in the interpreter and meets its
+    bound: the tiles reach the kernel, never the reference."""
+    import chip_smoke
+    K, shape, tiles = chip_smoke.LEAF_CASES[case]
+    err = chip_smoke.kernel_parity("none", K, shape, **tiles)
+    assert err <= chip_smoke.KERNEL_BOUND
+
+
 @pytest.mark.parametrize("K,k_block", [(8, 4), (32, 8), (48, 32), (7, 2)])
 @pytest.mark.parametrize("D", [256, 5000])
 def test_weighted_agg_tiled_k_matches_ref(K, k_block, D):
@@ -39,9 +73,11 @@ def test_weighted_agg_tiled_k_matches_ref(K, k_block, D):
 
 
 def test_weighted_agg_auto_tiles_large_k():
-    """K beyond MAX_SINGLE_K silently switches to the streamed layout."""
-    from repro.kernels.weighted_agg import MAX_SINGLE_K
+    """K beyond the int8 wire's MAX_SINGLE_K: the float path keeps the
+    whole client axis in one tile, whose K is not a sublane multiple."""
+    from repro.kernels.weighted_agg import MAX_SINGLE_K, _tiles
     K = MAX_SINGLE_K + 9
+    assert _tiles(K, (3000,), jnp.float32)[0] == K
     k1, k2 = jax.random.split(KEY)
     c = jax.random.uniform(k1, (K,), jnp.float32)
     d = jax.random.normal(k2, (K, 3000), jnp.float32)
@@ -78,17 +114,19 @@ def test_weighted_agg_property(K, D, block):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("K,D,tiles", [
-    (4, 1, (128, 4)), (4, 127, (128, 4)), (4, 2049, (2048, 4)),
-    (100, 199_210, (2048, 32)),          # mnist-static: the paper's MLP
-    (4, 129_100_224, (2048, 4)),         # mamba2-fed: mamba2-130m
+@pytest.mark.parametrize("K,shape,tiles", [
+    (4, (1,), (4, 1, 1)), (4, (127,), (4, 1, 127)),
+    (4, (1_000_000,), (4, 1, 65536)),
+    (100, (784, 200), (100, 8, 784)),    # mnist-static: w1 as 200 rows
+    (4, (24, 768, 1536), (4, 136, 1536)),  # mamba2-fed: in_x
 ])
-def test_tiles_pin_geometry(K, D, tiles):
-    """The kernel's own tile choice: a 2048-wide block shrunk to D
-    rounded up to 128 lanes, the whole client axis up to MAX_SINGLE_K
-    and 32-client slabs beyond it."""
+def test_tiles_pin_geometry(K, shape, tiles):
+    """The kernel's own (KBLK, TR, TM) for an f32 leaf (K, *shape): the
+    whole client axis, the whole minor dim (a (K, D) operand's D up to
+    ~4 MiB of tile), rows filling ~4 MiB with the f32 output block, and
+    mnist's (784, 200) read with its 784 on lanes."""
     from repro.kernels.weighted_agg import _tiles
-    assert _tiles(K, D) == tiles
+    assert _tiles(K, shape, jnp.float32) == tiles
 
 
 def _quantized(K, D, chunk, seed=0):

@@ -290,36 +290,41 @@ def _sampled_chunk_hlo(**kw):
 
 
 @pytest.mark.parametrize("kw, scopes", [
-    (dict(agg="flat"),
-     {"sample", "gather", "local_sgd", "flatten", "aggregate"}),
+    # the f32 wire stages nothing: each delta leaf enters its launch
+    (dict(agg="flat"), {"sample", "gather", "local_sgd", "aggregate"}),
     (dict(agg="flat", compression="int8"),
      {"sample", "gather", "local_sgd", "flatten", "aggregate"}),
     (dict(agg="tree"), {"sample", "gather", "local_sgd", "aggregate"}),
     (dict(engine_mode="client_sequential"),
      {"sample", "gather", "local_sgd", "aggregate"}),
-], ids=["flat", "flat-int8", "tree", "sequential"])
+    (dict(agg="flat", compression="bf16"),
+     {"sample", "gather", "local_sgd", "flatten", "aggregate"}),
+], ids=["flat", "flat-int8", "tree", "sequential", "flat-bf16"])
 def test_round_phases_are_named_scopes(kw, scopes):
     """Each phase of a round carries its jax.named_scope into the HLO's
-    op_name metadata, on every aggregation layout and mode."""
+    op_name metadata, on every aggregation layout, wire and mode."""
     import re
     hlo = _sampled_chunk_hlo(**kw)
     assert set(re.findall(r"fed\.([a-z_]+)", hlo)) == scopes
 
 
-@pytest.mark.parametrize("kw", [
-    dict(agg="flat"),
-    dict(agg="flat", compression="int8"),
+@pytest.mark.parametrize("kw, padded", [
+    (dict(agg="flat"), False),
+    (dict(agg="flat", compression="int8"), True),
 ], ids=["flat", "flat-int8"])
-def test_aggregation_kernel_names_its_operand_pad(kw):
-    """The weighted_agg kernel's zero pad carries the kernel module's own
-    scope, inside the round's fed.aggregate, and no phase name of the
-    round."""
+def test_aggregation_kernel_names_its_operand_pad(kw, padded):
+    """The int8 wire's zero pad of the kernel's operands carries the
+    kernel module's own scope, inside the round's fed.aggregate, and no
+    phase name of the round; the f32 wire pads no operand at all."""
     import re
 
     from repro.kernels.weighted_agg import PAD_SCOPE
     hlo = _sampled_chunk(**kw).compile().as_text()
     pads = set(re.findall(r'op_name="([^"]*)"', hlo))
     pads = {n for n in pads if f"/{PAD_SCOPE}/" in n}
+    if not padded:
+        assert not pads
+        return
     assert pads and all("/fed.aggregate/jit(weighted_agg" in n
                         and "fed.flatten" not in n for n in pads)
 

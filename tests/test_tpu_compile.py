@@ -105,19 +105,24 @@ def test_weighted_agg_compiles(one_chip, widths, model, K, dtype):
              sds((K,), jnp.float32), *operands)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8, "leaves"])
 def test_weighted_agg_sharded_compiles(four_chips, widths, monkeypatch,
                                        dtype):
     """The client-sharded reduction over a described four-chip mesh:
     each chip's launch over its 25 of 100 clients holds the kernel, and
-    a cross-chip all-reduce finishes it.  The sharded wrapper takes no
+    a cross-chip all-reduce finishes it; "leaves" passes the paper MLP's
+    f32 delta leaves, one launch each.  The sharded wrapper takes no
     ``interpret``, so the kernel is steered to Mosaic here."""
     monkeypatch.setattr(wa, "resolve_interpret",
                         lambda i: False if i is None else bool(i))
     sds = lambda shape, dt, *spec: jax.ShapeDtypeStruct(
         shape, dt, sharding=NamedSharding(four_chips, P("data", *spec)))
     K, D, kw = 100, widths["mnist_mlp"], {}
-    operands = (sds((K, D), dtype, None),)
+    if dtype == "leaves":
+        shapes = [(784, 200), (200,), (200, 200), (200,), (200, 10), (10,)]
+        operands = ([sds((K,) + s, jnp.float32) for s in shapes],)
+    else:
+        operands = (sds((K, D), dtype, None),)
     if dtype == jnp.int8:
         n_chunks = -(-D // CHUNK)
         operands = (sds((K, n_chunks * CHUNK), jnp.int8, None),
@@ -127,6 +132,32 @@ def test_weighted_agg_sharded_compiles(four_chips, widths, monkeypatch,
         c, *ops, mesh=four_chips, **kw)).lower(
         sds((K,), jnp.float32), *operands).compile().as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+
+
+@pytest.mark.parametrize("model,K", [("mnist_mlp", 100),
+                                     ("mamba2-130m", 4)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_weighted_agg_compiles_per_leaf(one_chip, model, K, dtype):
+    """The f32 and bf16 wires' launches, one per parameter leaf where
+    the leaf lies, at the cells' leaves: mamba2-130m's published ones
+    (lane-sparse minor dims, a second-minor of 4) and the paper MLP's."""
+    from repro.configs import get_config
+    from repro.configs.paper import MNIST_MLP
+    from repro.fed import LMTask
+    from repro.models.small import init_small
+
+    if model == "mnist_mlp":
+        init = lambda k: init_small(k, MNIST_MLP)
+    else:
+        init = LMTask(get_config("mamba2-130m"), seq_len=128).init_params
+    leaves = [jax.ShapeDtypeStruct((K,) + x.shape, dtype, sharding=one_chip)
+              for x in jax.tree.leaves(jax.eval_shape(
+                  init, jax.random.PRNGKey(0)))]
+    text = jax.jit(lambda c, xs: [wa.weighted_agg(c, x, interpret=False)
+                                  for x in xs]).lower(
+        jax.ShapeDtypeStruct((K,), jnp.float32, sharding=one_chip),
+        leaves).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == len(leaves)
 
 
 def test_ssd_scan_fwd_and_bwd_compile(one_chip):
