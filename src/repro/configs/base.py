@@ -100,6 +100,19 @@ class ArchConfig:
     router_score: str = "softmax"  # softmax | sigmoid (v3)
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.001
+    moe_shared_d_ff: int = 0       # shared-expert width; 0 => moe_d_ff x
+    #                                n_shared_experts
+    moe_routed_scale: float = 1.0  # routed gates scaled after the top-k
+    #                                renormalisation (Nemotron-H: 2.5)
+    # the experts this device holds, [moe_expert_offset, + held): 0 => all
+    # of them, capacity-bounded dispatch (moe._moe_ffn_dense); n => the
+    # router still scores every expert and the dropless grouped-matmul
+    # dispatch computes the held experts' part of the result
+    moe_experts_held: int = 0
+    moe_expert_offset: int = 0
+    # the grouped matmul as the Pallas kernel (megablox gmm); set for a
+    # round placed on one device (fed.task.LMTask.placed)
+    moe_kernel: bool = False
     # --- SSM (mamba2 SSD) ---
     ssm_d_state: int = 0
     ssm_expand: int = 2
@@ -107,9 +120,15 @@ class ArchConfig:
     ssm_n_groups: int = 1
     ssm_d_conv: int = 4
     ssm_chunk: int = 256
+    ssm_d_inner: int = 0           # 0 => ssm_expand x d_model
+    ssm_norm_groups: int = 1       # groups of the gated RMSNorm
     # the SSD as the fused Pallas scan (kernels/ssd_chunk) where its shapes
     # fit; set for a round placed on one device (fed.task.LMTask.placed)
     ssm_kernel: bool = False
+    # --- single-mixer layer pattern (Nemotron-H) ---
+    # one character a layer: M Mamba-2 mixer, E routed-expert FFN, *
+    # attention (params.PATTERN_KINDS); "" => the family's stacks
+    layer_pattern: str = ""
     # --- multimodal stub frontends ---
     n_patches: int = 0             # vlm: patch embeddings prepended
     n_codebooks: int = 0           # audio: EnCodec codebooks (summed embed)
@@ -154,7 +173,7 @@ class ArchConfig:
 
     @property
     def d_inner(self) -> int:      # ssm inner width
-        return self.ssm_expand * self.d_model
+        return self.ssm_d_inner or self.ssm_expand * self.d_model
 
     @property
     def ssm_n_heads(self) -> int:

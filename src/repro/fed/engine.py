@@ -445,6 +445,14 @@ class RoundEngine:
         tel.gauge("engine_ssd_fused_layers",
                   "SSD layers that run as the fused Pallas scan").set(
             task.ssd_fused_layers)
+        # routed experts an expert layer holds, and the (token, expert)
+        # slots a local step is expected to send them in each such layer
+        tel.gauge("engine_moe_experts_held",
+                  "routed experts each expert layer holds").set(
+            task.moe_experts_held)
+        tel.gauge("engine_moe_rows_per_step",
+                  "expected held-expert token slots a step, per layer").set(
+            self.B * task.tokens_per_sample * task.moe_slots_per_token)
         # set with _d_total, from the first span's params
         self._g_agg_launches = tel.gauge(
             "engine_agg_launches", "weighted_agg launches a round")

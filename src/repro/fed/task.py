@@ -86,6 +86,11 @@ class ClientTask:
     tokens_per_sample — tokens one sample trains (0 for non-LM tasks).
     ssd_fused_layers — SSD layers that run as the fused Pallas scan (0 for
                        tasks without SSD layers).
+    moe_experts_held — routed experts each expert layer holds (0 for tasks
+                       without them).
+    moe_slots_per_token — expected (token, expert) slots a token sends to
+                       an expert layer's held experts: top_k x held /
+                       experts (0 without routed experts).
     placed(n)        — the task as a round placed on n devices runs it
                        (itself, unless the placement picks its kernels).
     """
@@ -93,6 +98,8 @@ class ClientTask:
     buffers: Dict[str, BufferSpec] = {}
     tokens_per_sample: int = 0
     ssd_fused_layers: int = 0
+    moe_experts_held: int = 0
+    moe_slots_per_token: float = 0.0
 
     def placed(self, devices: int) -> "ClientTask":
         return self
@@ -164,14 +171,22 @@ class LMTask(ClientTask):
         self.buffers = {"tokens": BufferSpec(tail, np.int32)}
 
     # -- engine protocol ------------------------------------------------------
+    def _kernel_paths(self, cfg) -> tuple:
+        from repro.models.moe import gmm_kernel_fits
+        from repro.models.ssd import scan_fused
+        rows = self.seq_len * cfg.top_k
+        return (scan_fused(cfg, self.seq_len),
+                bool(cfg.moe_experts_held) and gmm_kernel_fits(cfg, rows))
+
     def placed(self, devices: int) -> "LMTask":
         """On one device the SSD runs as the fused Pallas scan where its
-        shapes fit (``ssm_kernel``); a round sharded over more keeps the
-        jnp path, since GSPMD cannot partition a Mosaic call."""
-        from repro.models.ssd import scan_fused
-        cfg = dataclasses.replace(self.cfg, ssm_kernel=devices == 1)
-        if scan_fused(cfg, self.seq_len) == scan_fused(self.cfg,
-                                                       self.seq_len):
+        shapes fit (``ssm_kernel``) and the held experts' grouped matmul
+        as the Pallas kernel (``moe_kernel``); a round sharded over more
+        keeps the jnp paths, since GSPMD cannot partition a Mosaic
+        call."""
+        cfg = dataclasses.replace(self.cfg, ssm_kernel=devices == 1,
+                                  moe_kernel=devices == 1)
+        if self._kernel_paths(cfg) == self._kernel_paths(self.cfg):
             return self                  # the placement changes no path
         task = copy.copy(self)
         task.cfg = cfg
@@ -185,6 +200,18 @@ class LMTask(ClientTask):
             return 0
         return sum(n for _, kind, n in block_kinds(self.cfg)
                    if kind in ("ssm", "hybrid"))
+
+    @property
+    def moe_experts_held(self) -> int:
+        if not self.cfg.n_experts:
+            return 0
+        return self.cfg.moe_experts_held or self.cfg.n_experts
+
+    @property
+    def moe_slots_per_token(self) -> float:
+        if not self.cfg.n_experts:
+            return 0.0
+        return self.cfg.top_k * self.moe_experts_held / self.cfg.n_experts
 
     def loss_fn(self, params, batch):
         from repro.models import transformer

@@ -1,7 +1,10 @@
 """Per-layer blocks: dense (GQA/MLA + MLP), MoE, SSM (Mamba2), hybrid
-(parallel attention + SSM heads, Hymba-style)."""
+(parallel attention + SSM heads, Hymba-style), and the single-mixer
+layers of a layer pattern (Nemotron-H): ssm, expert (routed-expert FFN
+alone) and attention (attention alone)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.models.attention import gqa_attention
@@ -12,9 +15,13 @@ from repro.models.ssd import mamba_mixer
 
 
 def _attn(p, x, cfg, positions, cache, decode):
-    if cfg.use_mla:
-        return mla_attention(p, x, cfg, positions, cache=cache, decode=decode)
-    return gqa_attention(p, x, cfg, positions, cache=cache, decode=decode)
+    """Attention under the device scope ``lm.attn``."""
+    with jax.named_scope("lm.attn"):
+        if cfg.use_mla:
+            return mla_attention(p, x, cfg, positions, cache=cache,
+                                 decode=decode)
+        return gqa_attention(p, x, cfg, positions, cache=cache,
+                             decode=decode)
 
 
 def block_apply(p, x, cfg, kind, positions, cache=None, decode=False):
@@ -30,6 +37,19 @@ def block_apply(p, x, cfg, kind, positions, cache=None, decode=False):
         if c is not None:
             new_cache["ssm"] = c
         x = x + y
+
+    elif kind == "expert":
+        h = apply_norm(x, p["ln1"], cfg)
+        y, aux_moe = moe_ffn(p["moe"], h, cfg)
+        aux = aux + aux_moe
+        x = x + y
+
+    elif kind == "attention":
+        h = apply_norm(x, p["ln1"], cfg)
+        a, ca = _attn(p["attn"], h, cfg, positions, cache.get("attn"), decode)
+        if ca is not None:
+            new_cache["attn"] = ca
+        x = x + a
 
     elif kind == "hybrid":
         h = apply_norm(x, p["ln1"], cfg)

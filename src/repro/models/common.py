@@ -33,12 +33,25 @@ def _rmsnorm_bwd(eps, res, dy):
     xhat = x32 * rstd
     g = dy32 * (1.0 + scale.astype(jnp.float32))
     dscale = jnp.sum(dy32 * xhat,
-                     axis=tuple(range(dy.ndim - 1))).astype(scale.dtype)
+                     axis=tuple(range(dy.ndim - scale.ndim))
+                     ).astype(scale.dtype)
     dx = rstd * (g - xhat * jnp.mean(g * xhat, axis=-1, keepdims=True))
     return dx.astype(x.dtype), dscale
 
 
 rmsnorm.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
+
+
+def group_rmsnorm(x, scale, groups: int, eps=1e-5):
+    """RMSNorm over each of ``groups`` equal slices of the last axis, each
+    with its slice of ``scale`` (Nemotron-H's gated norm: 8 groups of
+    512); one group is ``rmsnorm`` itself."""
+    if groups == 1:
+        return rmsnorm(x, scale, eps)
+    d = x.shape[-1]
+    y = rmsnorm(x.reshape(*x.shape[:-1], groups, d // groups),
+                scale.reshape(groups, d // groups), eps)
+    return y.reshape(x.shape)
 
 
 def layernorm(x, scale, bias, eps=1e-5):

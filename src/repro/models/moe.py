@@ -1,4 +1,6 @@
-"""Token-choice top-k MoE with capacity-bounded scatter dispatch.
+"""Token-choice top-k MoE: capacity-bounded scatter dispatch over every
+expert, or dropless grouped-matmul dispatch over the experts one device
+holds (``cfg.moe_experts_held``; ``_moe_ffn_held``).
 
 Design notes (see DESIGN.md §4): the dispatch is pure jnp (scatter/gather),
 so it is vmap-safe for the client-parallel federated mode and lowers under
@@ -16,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.weighted_agg import resolve_interpret
 from repro.models.common import activation_fn, mlp_apply
 from repro.models.sharding import constrain, current_mesh
 
@@ -41,12 +44,15 @@ def moe_ffn(p, x, cfg, ep: bool = False):
         "experts": {"w_gate","w_up": (E,d,f), "w_down": (E,f,d)},
         ["shared": dense-mlp params]}
     """
-    mesh = current_mesh()
-    if ep and mesh is not None and "model" in mesh.axis_names \
-            and x.ndim == 3 \
-            and x.shape[0] % _batch_div(mesh) == 0:
-        return _moe_ffn_ep(p, x, cfg, mesh)
-    return _moe_ffn_dense(p, x, cfg)
+    with jax.named_scope("lm.moe"):
+        if cfg.moe_experts_held:
+            return _moe_ffn_held(p, x, cfg)
+        mesh = current_mesh()
+        if ep and mesh is not None and "model" in mesh.axis_names \
+                and x.ndim == 3 \
+                and x.shape[0] % _batch_div(mesh) == 0:
+            return _moe_ffn_ep(p, x, cfg, mesh)
+        return _moe_ffn_dense(p, x, cfg)
 
 
 def _batch_div(mesh) -> int:
@@ -118,7 +124,9 @@ def _moe_ffn_dense(p, x, cfg):
 
 
 def _routing(xf, p, cfg):
-    """Shared router math: returns (top_i (T,k), gates (T,k), aux)."""
+    """Shared router math: returns (top_i (T,k), gates (T,k), aux).  The
+    sigmoid router's bias steers the choice only; the chosen scores are
+    renormalised and scaled by ``moe_routed_scale``."""
     T = xf.shape[0]
     E, k = cfg.n_experts, cfg.top_k
     logits = xf.astype(jnp.float32) @ p["router"].astype(jnp.float32)
@@ -132,6 +140,9 @@ def _routing(xf, p, cfg):
     _, top_i = jax.lax.top_k(sel, k)
     top_s = jnp.take_along_axis(scores, top_i, axis=-1)
     gates = top_s / (jnp.sum(top_s, -1, keepdims=True) + 1e-9)
+    gates = gates * cfg.moe_routed_scale
+    if not cfg.router_aux_coef:
+        return top_i, gates, jnp.float32(0.0)
     if cfg.router_score == "sigmoid":
         probs = scores / (jnp.sum(scores, -1, keepdims=True) + 1e-9)
     else:
@@ -212,3 +223,101 @@ def _moe_ffn_ep(p, x, cfg, mesh):
                 p["experts"]["w_gate"], p["experts"]["w_up"],
                 p["experts"]["w_down"], p.get("shared"))
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# dropless dispatch over the held experts
+# ---------------------------------------------------------------------------
+
+# m-dimension tile of the grouped-matmul kernel: the dispatched rows
+# (tokens x top_k) must be a multiple of it for the kernel path
+GMM_TILE_M = 128
+
+
+def gmm_kernel_fits(cfg, rows: int) -> bool:
+    """Whether ``grouped_matmul`` over ``rows`` dispatched rows runs as the
+    Pallas grouped matmul: the config asks for it (``moe_kernel``), Mosaic
+    compiles it (a TPU) and the rows fill whole m tiles."""
+    return (bool(cfg.moe_kernel) and not resolve_interpret(None)
+            and rows % GMM_TILE_M == 0)
+
+
+def _gmm_tile(dim: int) -> int:
+    """A k or n tile: 512, or the whole dimension below that (the kernel
+    masks a ragged last tile)."""
+    return min(dim, 512)
+
+
+def grouped_matmul(lhs, rhs, sizes, *, kernel: bool):
+    """Rows of ``lhs`` (m, k), sorted by group, times their group's
+    ``rhs`` (n, k, f), in float32.  ``sizes`` (n + 1,) counts each
+    group's rows; the last group, rows no held expert takes, has no
+    weights and comes back zero.  ``kernel``: the megablox Pallas
+    grouped matmul (forward ``gmm``, backward ``gmm`` and ``tgmm``;
+    interpreted off the TPU), else ``lax.ragged_dot``."""
+    if kernel:
+        from jax.experimental.pallas.ops.tpu.megablox import ops
+        tiles = (GMM_TILE_M, _gmm_tile(rhs.shape[1]), _gmm_tile(rhs.shape[2]))
+        return ops.gmm(lhs, rhs, sizes, jnp.float32, tiles, None, None,
+                       False, resolve_interpret(None))
+    return jax.lax.ragged_dot(lhs, rhs, sizes[:-1],
+                              preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inv):
+    """x[perm] for a permutation ``perm`` with inverse ``inv``; the
+    cotangent goes back by a gather through ``inv``, not a scatter."""
+    return jnp.take(x, perm, axis=0)
+
+
+def _permute_rows_fwd(x, perm, inv):
+    return jnp.take(x, perm, axis=0), (perm, inv)
+
+
+def _permute_rows_bwd(res, g):
+    perm, inv = res
+    return jnp.take(g, inv, axis=0), None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def _moe_ffn_held(p, x, cfg):
+    """The held experts' part of the layer, dropless.  Every token is
+    routed over all experts; its (token, choice) slots that name a held
+    expert are sorted by expert and each expert's rows go through one
+    grouped matmul, however many it gets; the other slots add nothing
+    (their experts live on other devices).  The shared expert, which
+    every device computes alike, is added whole."""
+    d = x.shape[-1]
+    xf = x.reshape(-1, d)
+    T, k = xf.shape[0], cfg.top_k
+    n, off = cfg.moe_experts_held, cfg.moe_expert_offset
+    kernel = gmm_kernel_fits(cfg, T * k)
+    act = activation_fn(cfg.activation)
+    with jax.named_scope("lm.moe.route"):
+        top_i, gates, aux = _routing(xf, p, cfg)
+        e = top_i.reshape(-1) - off
+        held = (e >= 0) & (e < n)
+        group = jnp.where(held, e, n).astype(jnp.int32)       # (T*k,)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        sizes = jnp.bincount(group, length=n + 1).astype(jnp.int32)
+        rows = _permute_rows(jnp.repeat(xf, k, axis=0), order, inv)
+        gate = jnp.where(held, gates.reshape(-1), 0.0)        # (T*k,)
+    with jax.named_scope("lm.moe.experts"):
+        ex = p["experts"]
+        h = grouped_matmul(rows, ex["w_up"], sizes, kernel=kernel)
+        if "w_gate" in ex:
+            h = act(grouped_matmul(rows, ex["w_gate"], sizes,
+                                   kernel=kernel)) * h
+        else:
+            h = act(h)
+        out = grouped_matmul(h.astype(x.dtype), ex["w_down"], sizes,
+                             kernel=kernel)                   # (T*k, d)
+        out = _permute_rows(out, inv, order) * gate[:, None]
+        y = jnp.sum(out.reshape(T, k, d), axis=1)
+        if "shared" in p:
+            y = y + mlp_apply(p["shared"], xf, cfg)
+    return y.astype(x.dtype).reshape(x.shape), aux

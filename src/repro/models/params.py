@@ -120,27 +120,32 @@ def _ssm_params(key, cfg, dtype, L=None):
 
 
 def _moe_params(key, cfg, dtype, L=None):
+    """The router scores all n_experts; only the held experts' weights
+    are here (cfg.moe_experts_held, all of them by default)."""
     s = (L,) if L else ()
     ks = _split(key, 6)
     d, f = cfg.d_model, cfg.moe_d_ff
     E = cfg.n_experts
+    held = cfg.moe_experts_held or E
     p = {
         "router": _dense(ks[0], s + (d, E), jnp.float32, scale=0.006),
         "experts": {
-            "w_gate": _dense(ks[1], s + (E, d, f), dtype),
-            "w_up": _dense(ks[2], s + (E, d, f), dtype),
-            "w_down": _dense(ks[3], s + (E, f, d), dtype),
+            "w_up": _dense(ks[2], s + (held, d, f), dtype),
+            "w_down": _dense(ks[3], s + (held, f, d), dtype),
         },
     }
+    if cfg.gated_mlp:
+        p["experts"]["w_gate"] = _dense(ks[1], s + (held, d, f), dtype)
     if cfg.router_score == "sigmoid":
         p["router_bias"] = jnp.zeros(s + (E,), jnp.float32)
     if cfg.n_shared_experts:
-        fs = cfg.moe_d_ff * cfg.n_shared_experts
+        fs = cfg.moe_shared_d_ff or cfg.moe_d_ff * cfg.n_shared_experts
         p["shared"] = {
-            "w_gate": _dense(ks[4], s + (d, fs), dtype),
             "w_up": _dense(ks[5], s + (d, fs), dtype),
             "w_down": _dense(ks[4], s + (fs, d), dtype),
         }
+        if cfg.gated_mlp:
+            p["shared"]["w_gate"] = _dense(ks[4], s + (d, fs), dtype)
     return p
 
 
@@ -149,6 +154,10 @@ def _block_params(key, cfg, kind, dtype, L):
     p = {"ln1": _norm_params(cfg, L)}
     if kind == "ssm":
         p["ssm"] = _ssm_params(ks[0], cfg, dtype, L)
+    elif kind == "expert":
+        p["moe"] = _moe_params(ks[1], cfg, dtype, L)
+    elif kind == "attention":
+        p["attn"] = _attn_params(ks[0], cfg, dtype, L)
     elif kind == "hybrid":
         p["attn"] = _attn_params(ks[0], cfg, dtype, L)
         p["ssm"] = _ssm_params(ks[1], cfg, dtype, L)
@@ -168,8 +177,21 @@ def _block_params(key, cfg, kind, dtype, L):
     return p
 
 
+# layer_pattern characters -> (params_key, block kind): every layer of a
+# kind is one row of that kind's stack, in the order the pattern has them
+PATTERN_KINDS = {"M": ("ssm_blocks", "ssm"),
+                 "E": ("expert_blocks", "expert"),
+                 "*": ("attn_blocks", "attention")}
+
+
 def block_kinds(cfg: ArchConfig):
     """Returns [(params_key, kind, n_layers), ...] stack layout."""
+    if cfg.layer_pattern:
+        if len(cfg.layer_pattern) != cfg.n_layers:
+            raise ValueError(f"layer_pattern has {len(cfg.layer_pattern)} "
+                             f"layers, n_layers is {cfg.n_layers}")
+        return [PATTERN_KINDS[ch] + (cfg.layer_pattern.count(ch),)
+                for ch in dict.fromkeys(cfg.layer_pattern)]
     if cfg.family == "ssm":
         return [("blocks", "ssm", cfg.n_layers)]
     if cfg.family == "hybrid":

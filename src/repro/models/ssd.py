@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ssd_chunk
-from repro.models.common import rmsnorm, safe_concat
+from repro.models.common import group_rmsnorm, safe_concat
 
 
 def segsum(x):
@@ -215,5 +215,6 @@ def mamba_mixer(p, u, cfg, cache=None, decode=False):
         # the D skip in the (Bb, S, H*P) layout x arrives in
         y = (y.reshape(*y.shape[:2], d_in)
              + jnp.repeat(p["D"].astype(y.dtype), Pd) * x)
-        y = rmsnorm(y * jax.nn.silu(z), p["ssm_norm"], cfg.norm_eps)
+        y = group_rmsnorm(y * jax.nn.silu(z), p["ssm_norm"],
+                          cfg.ssm_norm_groups, cfg.norm_eps)
         return y @ p["out_proj"], new_cache

@@ -14,7 +14,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models.blocks import block_apply
 from repro.models.common import apply_norm
-from repro.models.params import block_kinds
+from repro.models.params import PATTERN_KINDS, block_kinds
 from repro.models.rotary import sinusoidal
 from repro.models.sharding import BATCH, constrain
 
@@ -111,6 +111,26 @@ def _run_stack(stack_p, x, cfg, kind, positions, cache=None, decode=False):
     return x, jnp.sum(auxs), new_cache
 
 
+def _run_pattern(params, x, cfg, positions):
+    """The layers of ``cfg.layer_pattern`` in order, the j-th layer of a
+    kind being row j of its kind's stack (params.PATTERN_KINDS); each
+    layer rematerialised as ``cfg.remat`` says.  Returns (x, aux)."""
+    fns, seen = {}, {}
+    aux = jnp.float32(0.0)
+    for ch in cfg.layer_pattern:
+        name, kind = PATTERN_KINDS[ch]
+        j = seen[ch] = seen.get(ch, -1) + 1
+        if kind not in fns:
+            def layer(p, x, positions, kind=kind):
+                y, a, _ = block_apply(p, x, cfg, kind, positions)
+                return y, a
+            fns[kind] = jax.checkpoint(layer) if cfg.remat else layer
+        p = jax.tree.map(lambda a: a[j], params[name])
+        x, a = fns[kind](p, x, positions)
+        aux = aux + a
+    return x, aux
+
+
 def model_forward(params, cfg: ArchConfig, tokens, *, patch_emb=None,
                   positions=None, cache=None, decode=False):
     """Returns (hidden (B,S,d), aux_loss, new_cache_or_None).
@@ -132,13 +152,18 @@ def model_forward(params, cfg: ArchConfig, tokens, *, patch_emb=None,
 
     total_aux = jnp.float32(0.0)
     new_cache = {} if cache is not None else None
-    for name, kind, _L in block_kinds(cfg):
-        stack_cache = cache.get(name) if cache is not None else None
-        x, aux, nc = _run_stack(params[name], x, cfg, kind, positions,
-                                cache=stack_cache, decode=decode)
-        total_aux = total_aux + aux
-        if new_cache is not None:
-            new_cache[name] = nc
+    if cfg.layer_pattern:
+        if cache is not None:
+            raise NotImplementedError("a layer pattern runs without a cache")
+        x, total_aux = _run_pattern(params, x, cfg, positions)
+    else:
+        for name, kind, _L in block_kinds(cfg):
+            stack_cache = cache.get(name) if cache is not None else None
+            x, aux, nc = _run_stack(params[name], x, cfg, kind, positions,
+                                    cache=stack_cache, decode=decode)
+            total_aux = total_aux + aux
+            if new_cache is not None:
+                new_cache[name] = nc
     if cfg.seq_parallel and not decode and x.shape[1] > 1:
         # leave the sequence-sharded domain before the (token-chunked,
         # vocab-sharded) loss — avoids GSPMD resharding thrash there

@@ -1,16 +1,18 @@
-"""Fingerprint what both benchmark cells compile for the aggregation.
+"""Fingerprint what the benchmark cells compile.
 
 Compiles, for a described TPU v5e (one device of a v5e:2x2 topology; no
 chip needed), (i) ``mnist-static``'s round chunk as ``bench/`` builds it
 (100 clients, f32 wire, the config's ``chunk_size`` rounds, donated
-params as on the chip) and (ii) ``aggregate_deltas_flat`` over
-mamba2-130m's parameter shapes at K=4 on the f32 wire.  Each optimized
+params as on the chip), (ii) ``aggregate_deltas_flat`` over
+mamba2-130m's parameter shapes at K=4 on the f32 wire and (iii)
+``mamba2-fed``'s whole round as ``bench/`` builds it, its SSD on the
+fused scan as a one-chip placement takes it.  Each optimized
 HLO module is written to OUTDIR with its metadata, stack-frame tables
 and source locations stripped (a Mosaic kernel body is reduced to the
 hash of its MLIR without debug info), and its sha256 is printed.
 
-A change meant to leave both cells' compiled programs alone prints the
-same two hashes as its parent:
+A change meant to leave the cells' compiled programs alone prints the
+same hashes as its parent:
 
   JAX_PLATFORMS=cpu python tests/_hlo_identity.py <tree> <outdir>
 
@@ -33,6 +35,7 @@ from jax._src.lib.mlir import ir  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
+import repro.kernels.ssd_chunk as ssd_chunk  # noqa: E402
 import repro.kernels.weighted_agg as wa  # noqa: E402
 from bench.lib import harness  # noqa: E402
 from bench.lib.fedref import Policy  # noqa: E402
@@ -41,6 +44,7 @@ from repro.core.aggregation import aggregate_deltas_flat  # noqa: E402
 # an entry compiled for a described chip cannot be read back from a cache
 jax.config.update("jax_enable_compilation_cache", False)
 wa.resolve_interpret = lambda interpret: False      # Mosaic, as on a TPU
+ssd_chunk.resolve_interpret = wa.resolve_interpret
 DEV = SingleDeviceSharding(topologies.get_topology_desc(
     platform="tpu", topology_name="v5e:2x2").devices[0])
 
@@ -76,8 +80,8 @@ class _Captured(Exception):
     pass
 
 
-def mnist_chunk():
-    cell = harness.Cell(harness.manifest(ROOT), "mnist-static", 1, {})
+def round_chunk(workload: str):
+    cell = harness.Cell(harness.manifest(ROOT), workload, 1, {})
     prog = cell.driver.program(cell, cell.weights(Policy()))
     eng = prog.engine
     eng.donate = True                     # the chip's default
@@ -116,5 +120,6 @@ def mamba2_aggregation(K: int = 4):
 
 if __name__ == "__main__":
     os.makedirs(OUT, exist_ok=True)
-    dump("mnist_chunk", mnist_chunk())
+    dump("mnist_chunk", round_chunk("mnist-static"))
     dump("mamba2_agg", mamba2_aggregation())
+    dump("mamba2_round", round_chunk("mamba2-fed"))

@@ -64,3 +64,54 @@ def test_moe_grad_flows():
     assert np.isfinite(gnorm) and gnorm > 0
     # router receives gradient (through gate weights)
     assert float(jnp.sum(jnp.abs(g["router"]))) > 0
+
+
+# -- dropless dispatch over held experts ---------------------------------------
+def test_grouped_matmul_kernel_matches_ragged_dot():
+    """The Pallas grouped matmul (megablox, interpret mode here) gives
+    lax.ragged_dot's rows and gradients, the left-out last group's rows
+    zero, at a ragged split of 256 rows over 3 held experts."""
+    from repro.models.moe import grouped_matmul
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(256, 128)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(3, 128, 128)), jnp.float32)
+    sizes = jnp.asarray([70, 0, 101, 85], jnp.int32)   # last: not held
+    cot = jnp.asarray(rng.normal(size=(256, 128)), jnp.float32)
+
+    def run(kernel):
+        def f(x, w):
+            return jnp.sum(grouped_matmul(x, w, sizes, kernel=kernel) * cot)
+        with jax.default_matmul_precision("highest"):
+            return (grouped_matmul(x, w, sizes, kernel=kernel),
+                    jax.grad(f, argnums=(0, 1))(x, w))
+
+    got, want = run(True), run(False)
+    assert not np.any(np.asarray(got[0])[171:])
+    for g, h in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(h),
+                                   rtol=1e-4, atol=1e-3)
+
+
+def test_held_experts_path_and_task_share():
+    """moe_experts_held selects the dropless path (no aux loss at a zero
+    coefficient, only the held experts' weights); LMTask reports the
+    share the engine's gauges read."""
+    import dataclasses
+
+    from repro.fed import LMTask
+    cfg = dataclasses.replace(get_config("nemotron-3-nano-30b-a3b"),
+                              n_layers=3, layer_pattern="MEM", vocab=512)
+    task = LMTask(cfg, seq_len=4096)
+    assert task.moe_experts_held == 128
+    task = LMTask(dataclasses.replace(cfg, moe_experts_held=8), seq_len=64)
+    assert task.moe_experts_held == 8
+    assert task.moe_slots_per_token == 6 * 8 / 128
+    small = dataclasses.replace(
+        cfg, d_model=32, moe_d_ff=16, moe_shared_d_ff=24, n_experts=8,
+        top_k=2, moe_experts_held=4, dtype="float32")
+    p = _moe_params(KEY, small, jnp.float32)
+    assert p["experts"]["w_up"].shape == (4, 32, 16)
+    assert "w_gate" not in p["experts"]
+    y, aux = moe_ffn(p, 0.1 * jax.random.normal(KEY, (2, 8, 32)), small)
+    assert y.shape == (2, 8, 32) and float(aux) == 0.0
+    assert np.isfinite(np.asarray(y)).all()

@@ -90,8 +90,18 @@ def test_fused_scan_path_matches_jnp_path(monkeypatch, with_h0):
     """ssd_chunked on the fused scan (kernel=True) gives the jnp path's y,
     h_last and gradients at a shape that tiles on the chip (Q=128, two
     chunks, 2 groups of 2 heads of P=64)."""
+    _fused_matches_jnp(monkeypatch, with_h0, (2, 256, 4, 64, 2, 16))
+
+
+def test_fused_scan_eight_groups_matches_jnp_path(monkeypatch):
+    """The same at Nemotron-H's grouping: 8 groups of B and C, each
+    shared by 2 heads of P=64, chunks of Q=128."""
+    _fused_matches_jnp(monkeypatch, True, (1, 256, 16, 64, 8, 16))
+
+
+def _fused_matches_jnp(monkeypatch, with_h0, shape):
     rng = np.random.default_rng(5)
-    Bb, S, H, P, G, N = 2, 256, 4, 64, 2, 16
+    Bb, S, H, P, G, N = shape
     x = jnp.asarray(rng.normal(size=(Bb, S, H, P)), jnp.float32)
     dt = jnp.asarray(rng.uniform(0.01, 0.2, (Bb, S, H)), jnp.float32)
     A = jnp.asarray(-rng.uniform(0.5, 2.0, (H,)), jnp.float32)
@@ -167,3 +177,30 @@ def test_model_loss_on_fused_scan_matches_jnp(monkeypatch):
         scale = float(jnp.max(jnp.abs(h)))
         np.testing.assert_allclose(np.asarray(g), np.asarray(h),
                                    rtol=1e-4, atol=1e-4 * scale)
+
+
+def test_group_rmsnorm_one_group_is_rmsnorm_and_groups_normalise_apart():
+    """The gated norm by groups: one group is today's rmsnorm exactly
+    (mamba2-130m's mixer); with 8 groups each slice of the channels is
+    normalised on its own, gradients included."""
+    from repro.models.common import group_rmsnorm, rmsnorm
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(2, 5, 64)), jnp.float32)
+    s = jnp.asarray(rng.normal(size=(64,)) * 0.1, jnp.float32)
+    np.testing.assert_array_equal(np.asarray(group_rmsnorm(x, s, 1)),
+                                  np.asarray(rmsnorm(x, s)))
+
+    def plain(x, s):
+        xg = x.reshape(2, 5, 8, 8)
+        var = jnp.mean(jnp.square(xg), axis=-1, keepdims=True)
+        return (xg * jax.lax.rsqrt(var + 1e-5)
+                * (1.0 + s.reshape(8, 8))).reshape(x.shape)
+
+    w = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    got = jax.value_and_grad(lambda x, s: jnp.sum(
+        group_rmsnorm(x, s, 8) * w), argnums=(0, 1))(x, s)
+    want = jax.value_and_grad(lambda x, s: jnp.sum(plain(x, s) * w),
+                              argnums=(0, 1))(x, s)
+    for g, h in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(h),
+                                   rtol=1e-5, atol=1e-5)

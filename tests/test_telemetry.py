@@ -444,3 +444,109 @@ def test_fed_top_renders_headlessly_against_live_service():
         svc2.wait_rounds(4, timeout=60)
         frame = FedTop(svc2).frame()
     assert "fed_top" in frame and "paper" not in frame
+
+
+# -- the hybrid LM's scopes and gauges (Nemotron-H layer pattern) ---------------
+
+def _hybrid_cfg(**kw):
+    import dataclasses
+
+    from repro.configs import get_config
+    small = dict(n_layers=5, layer_pattern="MEM*E", d_model=64, vocab=256,
+                 n_heads=4, n_kv_heads=2, head_dim=16, n_experts=8, top_k=2,
+                 moe_experts_held=4, moe_d_ff=32, moe_shared_d_ff=48,
+                 ssm_d_inner=128, ssm_head_dim=16, ssm_d_state=16,
+                 ssm_n_groups=8, ssm_norm_groups=8, ssm_chunk=16)
+    small.update(kw)
+    return dataclasses.replace(get_config("nemotron-3-nano-30b-a3b"),
+                               **small)
+
+
+def _hybrid_scheduler(telemetry=None, seq=32, batch=2):
+    from repro.core.participation import TRACES
+    from repro.fed import Client, LMTask, StreamScheduler
+    task = LMTask(_hybrid_cfg(), seq_len=seq)
+    rng = np.random.default_rng(0)
+    clients = [Client(x=task.token_stream(rng, n=4, domain=d),
+                      trace=TRACES[d]) for d in range(2)]
+    return StreamScheduler(
+        clients=clients, task=task,
+        init_params=task.init_params(jax.random.PRNGKey(0)),
+        local_epochs=2, batch_size=batch, scheme="C", eta0=0.05, seed=0,
+        chunk_size=1, engine_mode="client_sequential", telemetry=telemetry)
+
+
+def test_hybrid_layers_are_named_scopes_inside_local_sgd():
+    """The pattern's attention and expert layers carry lm.attn and
+    lm.moe, the router and sort lm.moe.route and the grouped matmuls and
+    combine lm.moe.experts (both nested in lm.moe), forward and backward,
+    inside the client-sequential round's fed.local_sgd; the mixer keeps
+    lm.mixer with lm.ssd in it."""
+    import re
+    sch = _hybrid_scheduler()
+    eng = sch.engine
+    a = sch.state.span_args(0)
+    args = (sch.params, eng.data, eng.n, eng.s_cdf, sch.state.key,
+            np.asarray(a["active"], np.float32), np.int32(0),
+            np.asarray(a["p"], np.float32),
+            np.asarray(a["reboot_tau0"], np.int32),
+            np.asarray(a["reboot_boost"], np.float32), np.int32(0))
+    hlo = eng._get_fn(1, sampled=True).lower(*args).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in ("lm.attn", "lm.moe", "lm.moe.route", "lm.moe.experts",
+                  "lm.mixer", "lm.ssd"):
+        scoped = re.escape(scope) + r"(?![\w.])"
+        assert any(re.search(r"fed\.local_sgd\)?/.*" + scoped, n)
+                   for n in names), scope
+        assert any(re.search(r"fed\.local_sgd\)?/.*transpose\(.*"
+                             + scoped, n) for n in names), scope
+    for inner in ("route", "experts"):
+        assert any(re.search(r"lm\.moe/.*lm\.moe\." + inner, n)
+                   for n in names), inner
+    assert any(re.search(r"lm\.mixer/.*lm\.ssd", n) for n in names)
+
+
+def test_hybrid_gauges_at_engine_build():
+    """engine_moe_experts_held and engine_moe_rows_per_step (B x S x
+    top_k x held / experts) are set once at engine build; the client-
+    sequential round launches no weighted_agg; no SSD layer runs fused
+    on the CPU."""
+    tel = Telemetry()
+    sch = _hybrid_scheduler(telemetry=tel, seq=32, batch=2)
+    sch.run(1, eval_every=NO_EVAL)
+    g = tel.registry.gauge
+    assert g("engine_moe_experts_held").value == 4
+    assert g("engine_moe_rows_per_step").value == 2 * 32 * 2 * 4 / 8
+    assert g("engine_agg_launches").value == 0
+    assert g("engine_ssd_fused_layers").value == 0
+    mlp = Telemetry()
+    make_scheduler(make_clients(3, seed=0), seed=0, telemetry=mlp)
+    assert mlp.registry.gauge("engine_moe_experts_held").value == 0
+    assert mlp.registry.gauge("engine_moe_rows_per_step").value == 0
+
+
+def test_hybrid_kernels_on_a_one_chip_placement(monkeypatch):
+    """At the nemotron3-fed cell's widths (7 layers MEMEM*E, 8 of 128
+    experts, S=4096, chunk 128, 8 groups) a round placed on one TPU
+    runs its 3 M layers on the fused scan and its experts on the grouped
+    matmul kernel: engine_ssd_fused_layers reads 3, and 1,536 held
+    slots a step; on the CPU, or sharded, it keeps the jnp paths."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.fed import LMTask
+    from repro.kernels import ssd_chunk
+    from repro.models import moe
+    cfg = dataclasses.replace(get_config("nemotron-3-nano-30b-a3b"),
+                              n_layers=7, layer_pattern="MEMEM*E",
+                              vocab=16384, moe_experts_held=8)
+    task = LMTask(cfg, seq_len=4096)
+    assert task.placed(1) is task                      # CPU: jnp paths
+    assert task.placed(1).ssd_fused_layers == 0
+    monkeypatch.setattr(ssd_chunk, "resolve_interpret", lambda i: False)
+    monkeypatch.setattr(moe, "resolve_interpret", lambda i: False)
+    one = task.placed(1)
+    assert one.ssd_fused_layers == 3
+    assert one.cfg.moe_kernel and not task.cfg.moe_kernel
+    assert task.placed(4) is task
+    assert 1 * 4096 * one.moe_slots_per_token == 1536
